@@ -34,6 +34,7 @@ import numpy as np
 
 from repro.core import TimingEvaluator, autotune
 from repro.core.space import ConfigurationSpace
+from repro.launch.device import device_info
 
 EVALS = int(os.environ.get("REPRO_BENCH_EVALS", "30"))
 SCALE = os.environ.get("REPRO_BENCH_SCALE", "small")
@@ -42,7 +43,7 @@ LEARNER = os.environ.get("REPRO_BENCH_LEARNER", "RF")
 
 def bench_meta() -> dict:
     """Provenance stamp shared by every ``BENCH_*.json`` artifact: which
-    host/commit produced the numbers and when — so two artifacts are
+    host/commit/device produced the numbers and when — so two artifacts are
     comparable (or visibly not) without archaeology."""
     try:
         sha = subprocess.run(
@@ -52,7 +53,11 @@ def bench_meta() -> dict:
         ).stdout.strip() or None
     except Exception:  # noqa: BLE001 — no git is fine (tarball checkout)
         sha = None
+    dev = device_info()
     return {
+        "device_platform": dev["platform"],
+        "device_kind": dev["device_kind"],
+        "device_count": dev["count"],
         "host": socket.gethostname(),
         "platform": platform.platform(),
         "machine": platform.machine(),

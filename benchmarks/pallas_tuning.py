@@ -72,5 +72,8 @@ def tune_all(max_evals: int | None = None, store: TuningStore | str | None = Non
 
 
 if __name__ == "__main__":
+    from repro.launch.device import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks.common import emit
     emit(tune_all())

@@ -31,7 +31,12 @@ class VariantSpec:
     # timing (TimingEvaluator); inject e.g. a cost-model scorer instead.
     make_evaluator: Callable[[Callable], Callable] | None = None
 
-    def default_config(self, target: str = "host") -> dict:
+    def default_config(self, target: str | None = None) -> dict:
+        """The space default for ``target``, which defaults to the device
+        this process runs on (``"tpu"`` on a TPU, ``"host"`` elsewhere)."""
+        if target is None:
+            from repro.kernels.util import default_target
+            target = default_target()
         return self.space(target).default_configuration()
 
 

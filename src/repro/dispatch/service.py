@@ -39,6 +39,7 @@ from repro.dispatch.registry import get as get_variant
 from repro.dispatch.signature import shape_signature, signature_key
 from repro.dispatch.store import TuningStore
 from repro.guard.faults import fault_point
+from repro.kernels.util import default_target
 from repro.obs.metrics import get_registry, summarize_histograms
 from repro.obs.trace import get_tracer
 
@@ -51,7 +52,7 @@ class DispatchService:
         store: TuningStore | None = None,
         *,
         backend: str = "host",
-        target: str = "host",
+        target: str | None = None,
         distance_threshold: float = 1.0,
         staleness_sec: float | None = None,
         tuner: Any | None = None,
@@ -66,7 +67,9 @@ class DispatchService:
         # fast-hit path's one-lock contract holds with metrics enabled.
         self.metrics = metrics if metrics is not None else get_registry()
         self.backend = backend
-        self.target = target
+        # tile spaces and defaults follow the device: on a TPU the space
+        # defaults pick the Pallas kernels, on a host the XLA fallbacks
+        self.target = target if target is not None else default_target()
         self.distance_threshold = distance_threshold
         self.staleness_sec = staleness_sec
         self.tuner = tuner
@@ -309,6 +312,7 @@ class DispatchService:
                                 signature=sig_key, backend=backend)
 
         timed.__wrapped__ = fn
+        timed.config = dict(config) if config is not None else None
         return timed
 
     def _enqueue_tuning(self, spec, kernel, sig, args, static_kw) -> None:
@@ -386,10 +390,16 @@ class DispatchService:
         age) when one is attached, the attached paged KV cache's
         page/token accounting (under ``kv_cache``), and — under
         ``execute_latency`` — per-signature p50/p99 execute latency from
-        the obs registry's histograms. All pre-existing flat keys are
-        unchanged."""
+        the obs registry's histograms, and under ``executables`` the config
+        each cached kernel executable was built from (so a caller can see
+        which implementation, e.g. ``impl == "pallas"``, served a
+        signature). All pre-existing flat keys are unchanged."""
         with self._lock:
             out = dict(self.stats)
+            execs = [(k, fn) for k, fn in self._exec.items() if k[0] != "__fn__"]
+        out["executables"] = [
+            {"kernel": k[0], "signature": k[1], "config": fn.config}
+            for k, fn in execs]
         if self.tuner is not None and getattr(self.tuner, "stats", None):
             out.update(self.tuner.stats)
         if self._sync is not None:

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.util import cdiv, default_interpret, pad_to, tpu_compiler_params, unpad
+from repro.kernels.util import cdiv, default_interpret, pad_to, unpad
 
 __all__ = ["covariance"]
 
@@ -106,7 +106,7 @@ def covariance(
         out_specs=pl.BlockSpec((bi, bj), lambda *g: (gi(*g), gj(*g))),
         out_shape=jax.ShapeDtypeStruct((Mp, Mp), data.dtype),
         scratch_shapes=[pltpu.VMEM((bi, bj), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -37,7 +37,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.util import cdiv, default_interpret, pad_to, tpu_compiler_params
+from repro.kernels.util import cdiv, default_interpret, pad_to
 
 __all__ = ["decode_attention", "chunked_decode_xla", "decode_ref"]
 
@@ -81,7 +81,12 @@ def _decode_kernel(cp_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
 
     hgG = (hg, q.shape[1], bk)
     slots = kb * bk + jax.lax.broadcasted_iota(jnp.int32, hgG, 2)
-    cp = cp_ref[pl.ds(i * hg, hg)].reshape(hg, 1, 1)
+    # SMEM holds scalars only: read each row's position as one and place it
+    # on that row of the block
+    row = jax.lax.broadcasted_iota(jnp.int32, hgG, 0)
+    cp = jnp.full(hgG, cp_ref[i * hg], jnp.int32)
+    for r in range(1, hg):
+        cp = jnp.where(row == r, cp_ref[i * hg + r], cp)
     _, valid = _decode_mask(slots, cp, s_real=s_real, ring=ring, window=window)
     s = jnp.where(valid, s, _NEG)
 
@@ -156,7 +161,7 @@ def decode_attention(
                           s_real=S, ring=ring, window=int(window or 0)),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qp.shape, q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.util import cdiv, default_interpret, pad_to, tpu_compiler_params
+from repro.kernels.util import cdiv, default_interpret, pad_to
 
 __all__ = ["flash_attention", "flash_hbm_bytes", "xla_attention_hbm_bytes"]
 
@@ -112,7 +112,7 @@ def flash_attention(
             pltpu.VMEM((bq, 1), jnp.float32),    # running max
             pltpu.VMEM((bq, 1), jnp.float32),    # running denominator
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

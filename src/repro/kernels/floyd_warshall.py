@@ -18,7 +18,9 @@ VMEM across the k-sweep exactly as CPU blocking keeps them in cache.
 ``allow_semiring_reassociation=True`` is mandatory to run the blocked kernel
 — the explicit, caller-visible analog of ``-polly-pragma-ignore-depcheck``.
 Knobs: ``bs`` (block), ``bi``/``bj`` (phase-3 grid tiles), ``unroll`` (the
-k-sweep unroll factor inside the kernel, the paper's unroll-pragma analog).
+k-sweep unroll factor inside the kernel, the paper's unroll-pragma analog;
+the sweep steps in chunks of 8 k, and ``unroll`` chunks share one loop
+iteration).
 """
 
 from __future__ import annotations
@@ -30,22 +32,36 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.util import cdiv, default_interpret, pad_to, tpu_compiler_params
+from repro.kernels.util import cdiv, default_interpret, pad_to
 
 __all__ = ["floyd_warshall", "minplus_update"]
 
 _BIG = 1.0e18  # padding distance: +inf surrogate that survives addition
 
 
-def _minplus_kernel(d_ref, a_ref, b_ref, o_ref, *, bs: int, unroll: int):
-    """o = min(d, min_k a[:, k] + b[k, :]) over the bs-wide contraction."""
-    acc = d_ref[...]
+# k-steps per loop chunk: one sublane tile of the transposed A panel
+_KC = 8
 
-    def body(k, acc):
-        return jnp.minimum(acc, a_ref[:, k][:, None] + b_ref[k, :][None, :])
 
-    acc = jax.lax.fori_loop(0, bs, body, acc, unroll=unroll)
-    o_ref[...] = acc
+def _minplus_kernel(d_ref, a_ref, b_ref, o_ref, at_ref, *, bs: int, unroll: int):
+    """o = min(d, min_k a[:, k] + b[k, :]) over the bs-wide contraction.
+
+    Column k of the A block sits at a dynamic lane offset, which Mosaic
+    cannot load. The block is transposed once into VMEM so that k indexes
+    rows; each 8-row chunk is transposed back in registers, giving its 8
+    columns at static lane offsets."""
+    at_ref[...] = a_ref[...].T
+
+    def body(c, acc):
+        k0 = pl.multiple_of(c * _KC, _KC)
+        cols = at_ref[pl.ds(k0, _KC), :].T          # (bi, KC)
+        rows = b_ref[pl.ds(k0, _KC), :]             # (KC, bj)
+        for r in range(_KC):
+            acc = jnp.minimum(acc, cols[:, r:r + 1] + rows[r:r + 1, :])
+        return acc
+
+    o_ref[...] = jax.lax.fori_loop(0, bs // _KC, body, d_ref[...],
+                                   unroll=unroll)
 
 
 def minplus_update(
@@ -67,10 +83,13 @@ def minplus_update(
     bi = min(bi, n)
     bj = min(bj, m)
 
+    # the contraction pads to whole chunks: a padded k pairs _BIG with
+    # _BIG, which never undercuts a real distance
     Dp = pad_to(D, (bi, bj), value=_BIG)
-    Ap = pad_to(A, (bi, 1), value=_BIG)
-    Bp = pad_to(B, (1, bj), value=_BIG)
+    Ap = pad_to(A, (bi, _KC), value=_BIG)
+    Bp = pad_to(B, (_KC, bj), value=_BIG)
     ni, nj = Dp.shape[0] // bi, Dp.shape[1] // bj
+    bs = Ap.shape[1]
 
     out = pl.pallas_call(
         functools.partial(_minplus_kernel, bs=bs, unroll=unroll),
@@ -82,7 +101,8 @@ def minplus_update(
         ],
         out_specs=pl.BlockSpec((bi, bj), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct(Dp.shape, D.dtype),
-        compiler_params=tpu_compiler_params(
+        scratch_shapes=[pltpu.VMEM((bs, bi), A.dtype)],   # A block, transposed
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")
         ),
         interpret=interpret,

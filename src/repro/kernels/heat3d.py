@@ -29,16 +29,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.util import cdiv, default_interpret, pad_to, tpu_compiler_params
+from repro.kernels.util import cdiv, default_interpret, pad_to
 
 __all__ = ["heat3d", "heat3d_step"]
 
 
-def _masked_update(ext: jnp.ndarray, g_rows: jnp.ndarray, n0: int) -> jnp.ndarray:
+def _masked_update(ext: jnp.ndarray, row0, n0: int) -> jnp.ndarray:
     """One masked stencil application on an extended slab.
 
-    ``ext``: (L, N1, N2); rows 1..L-2 get the update where their *global* row
-    index is interior; everything else copies through. Rows whose global index
+    ``ext``: (L, N1, N2) whose row 0 has global row index ``row0``; rows
+    1..L-2 get the update where their *global* row index is interior;
+    everything else copies through. Rows whose global index
     falls outside [0, n0) hold garbage, but garbage only feeds rows that the
     mask forces to copy, so it never propagates into kept values.
     """
@@ -56,9 +57,10 @@ def _masked_update(ext: jnp.ndarray, g_rows: jnp.ndarray, n0: int) -> jnp.ndarra
 
     new = 0.125 * i_diff + 0.125 * j_diff + 0.125 * k_diff + mid
 
-    gi = g_rows[1:-1][:, None, None]
-    jj = jnp.arange(n1)[None, :, None]
-    kk = jnp.arange(n2)[None, None, :]
+    # 3-D iotas: Mosaic cannot reshape a 1-D index vector into the slab
+    gi = row0 + 1 + jax.lax.broadcasted_iota(jnp.int32, mid.shape, 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, mid.shape, 1)
+    kk = jax.lax.broadcasted_iota(jnp.int32, mid.shape, 2)
     interior = (
         (gi > 0) & (gi < n0 - 1)
         & (jj > 0) & (jj < n1 - 1)
@@ -73,9 +75,8 @@ def _heat_kernel(prev_ref, cur_ref, next_ref, o_ref, *, bi: int, h: int, n0: int
     ext = jnp.concatenate(
         [prev_ref[...][-h:], cur_ref[...], next_ref[...][:h]], axis=0
     )  # (bi + 2h, N1, N2)
-    g_rows = i * bi - h + jnp.arange(bi + 2 * h)
     for _ in range(h):  # fused time steps (temporal blocking)
-        ext = _masked_update(ext, g_rows, n0)
+        ext = _masked_update(ext, i * bi - h, n0)
     o_ref[...] = ext[h : h + bi]
 
 
@@ -105,7 +106,7 @@ def heat3d_step(
         ],
         out_specs=pl.BlockSpec((bi, n1, n2), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(Ap.shape, A.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)
         ),
         interpret=interpret,

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.util import cdiv, default_interpret, pad_to, tpu_compiler_params, unpad
+from repro.kernels.util import cdiv, default_interpret, pad_to, unpad
 
 __all__ = ["tiled_matmul"]
 
@@ -107,7 +107,7 @@ def tiled_matmul(
         ],
         out_specs=pl.BlockSpec((bm, bn), o_map),
         out_shape=jax.ShapeDtypeStruct((ap.shape[0], bp.shape[1]), out_dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -8,9 +8,10 @@ shape tables drifted independently.
 
   * ``BENCH_DIMS`` — host-timeable sizes (backend B1, the paper's Core-i7
     role): small enough that one evaluation is milliseconds on CPU.
-  * ``LARGE_SHAPES`` — the paper's LARGE dataset sizes (backend B2, scored
-    by the analytic TPU cost model); the model kernels use a 16-head
-    4k-context serving shape as their LARGE analog.
+  * ``LARGE_SHAPES`` — the paper's LARGE dataset sizes: what the analytic
+    TPU cost model scores (backend B2) and what a campaign times on a TPU
+    (:func:`tpu_problem`); the model kernels use a 16-head 4k-context
+    serving shape as their LARGE analog.
   * ``DEFAULTS_TPU`` — the MXU-default schedules the benchmark compares
     autotuned configs against.
 
@@ -39,12 +40,14 @@ __all__ = [
     "LARGE_SHAPES",
     "PROXY_DIMS",
     "bench_problem",
+    "campaign_dims",
     "dims_from_signature",
     "fidelity_ready",
     "fidelity_readiness",
     "make_cost_evaluator",
     "problem_signature_for",
     "register_cost_backend",
+    "tpu_problem",
 ]
 
 # host-timeable problem dims behind the bench problems (heat3d includes its
@@ -133,16 +136,55 @@ def bench_problem(name: str, dims: tuple | None = None):
 BENCH_PROBLEMS = {name: (lambda n=name: bench_problem(n)) for name in BENCH_DIMS}
 
 
+def tpu_problem(name: str, dims: tuple | None = None):
+    """Variant factory for ``name`` on a TPU: the Pallas op of
+    :mod:`repro.kernels.ops` (the model kernels' dispatch builders for the
+    attention kernels) at the paper's :data:`LARGE_SHAPES`, or at ``dims``.
+    The op runs in interpret mode off a TPU, which only tests want."""
+    import functools
+
+    from repro.kernels import model_kernels as MK
+    from repro.kernels import ops
+    from repro.kernels import ref as R
+
+    dims = LARGE_SHAPES[name] if dims is None else tuple(dims)
+    if name == "flash_attention":
+        return MK.flash_attention_host(MK.init_flash_attention(*dims))
+    if name == "decode_attention":
+        return MK.decode_attention_host(MK.init_decode_attention(*dims))
+    if name == "matmul":
+        op, args = ops.matmul_op, MK.init_matmul(*dims)
+    elif name == "heat3d":
+        op = functools.partial(ops.heat3d_op, tsteps=dims[1])
+        args = R.init_heat3d(dims[0])
+    else:
+        op, args = getattr(ops, f"{name}_op"), getattr(R, f"init_{name}")(*dims)
+
+    def factory(cfg):
+        return functools.partial(op, config=dict(cfg)), args
+
+    return factory
+
+
+def campaign_dims(kernel: str, backend: str) -> tuple:
+    """Problem dims a campaign on ``backend`` runs at: the paper's
+    :data:`LARGE_SHAPES` for the cost model and for timings on a TPU,
+    :data:`BENCH_DIMS` for timings on a host."""
+    from repro.kernels.util import default_target
+
+    if backend == "cost" or default_target() == "tpu":
+        return LARGE_SHAPES[kernel]
+    return BENCH_DIMS[kernel]
+
+
 def problem_signature_for(kernel: str, backend: str):
     """Per-argument store signature for a kernel's canonical problem — the
     same scheme ``repro.dispatch`` derives from runtime args, so configs
-    published offline resolve at ``dispatch()`` time. Host-backend campaigns
-    run at :data:`BENCH_DIMS`; cost-backend campaigns at the paper's
-    :data:`LARGE_SHAPES`."""
+    published offline resolve at ``dispatch()`` time. The dims are
+    :func:`campaign_dims`."""
     from repro.kernels.ref import problem_signature
 
-    dims = LARGE_SHAPES[kernel] if backend == "cost" else BENCH_DIMS[kernel]
-    return problem_signature(kernel, *dims)
+    return problem_signature(kernel, *campaign_dims(kernel, backend))
 
 
 def dims_from_signature(kernel: str, signature) -> tuple:

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.util import cdiv, default_interpret, pad_to, tpu_compiler_params, unpad
+from repro.kernels.util import cdiv, default_interpret, pad_to, unpad
 
 __all__ = ["syr2k"]
 
@@ -124,7 +124,7 @@ def syr2k(
             pltpu.VMEM((bi, bk), A.dtype),      # packed A tile
             pltpu.VMEM((bi, bk), B.dtype),      # packed B tile
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

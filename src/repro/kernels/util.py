@@ -1,38 +1,26 @@
-"""Shared kernel utilities: padding, interpret-mode policy, alignment."""
+"""Shared kernel utilities: padding, the device-derived target, alignment."""
 
 from __future__ import annotations
 
-import os
-
 import jax
 import jax.numpy as jnp
-from jax.experimental.pallas import tpu as _pltpu
 
-__all__ = ["default_interpret", "cdiv", "pad_to", "unpad", "tpu_compiler_params",
+__all__ = ["default_interpret", "default_target", "cdiv", "pad_to", "unpad",
            "TPU_LANE", "TPU_SUBLANE"]
-
-# jax < 0.5 names the Mosaic params class TPUCompilerParams; newer releases
-# renamed it CompilerParams — resolve whichever this jax ships
-_CompilerParams = getattr(_pltpu, "CompilerParams", None) \
-    or getattr(_pltpu, "TPUCompilerParams")
-
-
-def tpu_compiler_params(**kw):
-    """Version-portable ``pltpu.CompilerParams`` (e.g. dimension_semantics)."""
-    return _CompilerParams(**kw)
 
 TPU_LANE = 128     # last-dim tile of the TPU vector unit / MXU
 TPU_SUBLANE = 8    # second-to-last-dim tile (f32)
 
 
-def default_interpret() -> bool:
-    """Pallas kernels run in interpret mode unless a real TPU is attached.
+def default_target() -> str:
+    """The tuning target of the device this process runs on: ``"tpu"`` on a
+    TPU (Pallas kernels, MXU-aligned tile spaces), ``"host"`` anywhere else
+    (the XLA molds and the paper's CPU tile sequences)."""
+    return "tpu" if jax.default_backend() == "tpu" else "host"
 
-    Override with REPRO_PALLAS_INTERPRET=0/1.
-    """
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
+
+def default_interpret() -> bool:
+    """Pallas kernels run in interpret mode exactly when no TPU is attached."""
     return jax.default_backend() != "tpu"
 
 

@@ -185,10 +185,9 @@ def heat3d_variant(A, tsteps, *, bi, fuse_t=1):
 
         def block(i):
             ext = jax.lax.dynamic_slice(Xh, (i * bi, 0, 0), (bi + 2 * h, n1, n2))
-            g_rows = i * bi - h + jnp.arange(bi + 2 * h)
             e = ext
             for _ in range(h):
-                e = _masked_update(e, g_rows, n0)
+                e = _masked_update(e, i * bi - h, n0)
             return e[h : h + bi]
 
         blocks = jax.lax.map(block, jnp.arange(ni))

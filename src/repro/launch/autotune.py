@@ -4,8 +4,13 @@ now a thin adapter over :class:`repro.engine.Campaign`.
     PYTHONPATH=src python -m repro.launch.autotune --kernel syr2k \
         --max-evals 30 --learner RF --db results/syr2k_rf
 
-Kernels are tuned on the host-timed backend (B1) at bench sizes; pass
---backend cost for the TPU-model backend (B2) at paper LARGE sizes.
+--backend host times each configuration on the device this process runs
+on. On a TPU that is the Pallas kernel of repro.kernels.ops over the TPU
+tile space at the paper's LARGE sizes; anywhere else it is the blocked XLA
+mold of repro.kernels.variants over the paper's CPU tile lists at bench
+sizes (backend B1). --backend cost scores the TPU tile space with the
+analytic model (B2) at LARGE sizes. The exit code is 1 when no evaluation
+succeeded.
 
 --parallel N keeps N candidate evaluations in flight (constant-liar
 batching over a thread pool); N=1 is the paper's serial loop, bit-for-bit.
@@ -36,10 +41,13 @@ from repro.core import TimingEvaluator, autotune
 from repro.core.findmin import importance_report
 from repro.kernels.problems import (
     bench_problem,
+    campaign_dims,
     make_cost_evaluator,
     problem_signature_for,
+    tpu_problem,
 )
 from repro.kernels.spaces import KERNEL_SPACES, kernel_space
+from repro.kernels.util import default_target
 
 
 def main(argv=None) -> int:
@@ -84,13 +92,18 @@ def main(argv=None) -> int:
                  "--backend cost IS the cascade's rung 0")
     if (args.rung_budgets or args.promote) and not args.cascade:
         ap.error("--rung-budgets/--promote only apply with --cascade")
+    target = default_target()
+    if args.cascade and target == "tpu":
+        ap.error("--cascade times the host molds at bench sizes; on a TPU "
+                 "run the flat campaign, which times the Pallas kernels")
 
-    if args.backend == "host":
-        evaluator = TimingEvaluator(bench_problem(args.kernel), repeats=2, warmup=1)
-        space = kernel_space(args.kernel, target="host", seed=args.seed)
-    else:
+    if args.backend == "cost":
         evaluator = make_cost_evaluator(args.kernel)
         space = kernel_space(args.kernel, target="tpu", seed=args.seed)
+    else:
+        problem = tpu_problem if target == "tpu" else bench_problem
+        evaluator = TimingEvaluator(problem(args.kernel), repeats=2, warmup=1)
+        space = kernel_space(args.kernel, target=target, seed=args.seed)
 
     sig = problem_signature_for(args.kernel, args.backend)
     warm_cfgs, warm_recs = None, None
@@ -114,11 +127,9 @@ def main(argv=None) -> int:
     feasibility = None
     if args.prune_infeasible:
         from repro.analyze.feasibility import feasibility_filter
-        from repro.kernels.problems import BENCH_DIMS, LARGE_SHAPES
-        dims = (BENCH_DIMS if args.backend == "host" else LARGE_SHAPES)[args.kernel]
         feasibility = feasibility_filter(
-            args.kernel, dims=dims,
-            target="host" if args.backend == "host" else "cost")
+            args.kernel, dims=campaign_dims(args.kernel, args.backend),
+            target="cost" if args.backend == "cost" else target)
 
     cascade_stats = None
     if args.cascade:
@@ -155,7 +166,12 @@ def main(argv=None) -> int:
         print(f"feasibility: pruned {res.timings.get('n_pruned', 0)} "
               f"statically-infeasible candidate(s) from the acquisition pool")
 
-    if args.store and res.best is not None:
+    if res.best is None:
+        print(res.summary())
+        print("no evaluation succeeded")
+        return 1
+
+    if args.store:
         from repro.dispatch import TuningRecord, TuningStore
         TuningStore(args.store).put(TuningRecord(
             kernel=args.kernel, signature=sig, backend=args.backend,
@@ -176,4 +192,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.device import enable_compile_cache
+
+    enable_compile_cache()
     raise SystemExit(main())

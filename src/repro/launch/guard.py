@@ -149,4 +149,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.device import enable_compile_cache
+
+    enable_compile_cache()
     raise SystemExit(main())

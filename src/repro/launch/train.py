@@ -4,13 +4,15 @@
         --steps 200 --reduced --batch 8 --seq 128 --ckpt-dir /tmp/ckpt
 
 Fault tolerance: checkpoints every --ckpt-every steps (async), resumes from
-the latest checkpoint at startup, monitors per-step stragglers.
+the latest checkpoint at startup, monitors per-step stragglers. The exit
+code is 1 when any step's loss is not finite.
 """
 
 from __future__ import annotations
 
 import argparse
 import dataclasses
+import math
 import time
 
 import jax
@@ -58,23 +60,27 @@ def main(argv=None) -> int:
             params, opt = state["params"], state["opt"]
             print(f"[train] resumed from step {start}")
 
+    # params and optimizer state are replaced every step: donating them lets
+    # the update reuse their buffers instead of holding two copies
     step_fn = jax.jit(make_train_step(cfg, lr=args.lr, accum=args.accum,
-                                      remat=args.remat))
+                                      remat=args.remat), donate_argnums=(0, 1))
     stream = SyntheticLM(cfg.vocab_size, args.seq, args.batch, seed=args.seed)
     mon = StragglerMonitor()
 
     t0 = time.perf_counter()
+    diverged = False
     for s in range(start, args.steps):
         batch = make_batch(stream, s)
         mon.start()
         params, opt, m = step_fn(params, opt, batch)
-        jax.block_until_ready(m["loss"])
+        loss = float(m["loss"])
         dur, slow = mon.stop()
+        diverged |= not math.isfinite(loss)
         if slow:
             print(f"[train] step {s}: straggler ({dur:.2f}s vs EWMA {mon.ewma:.2f}s)")
         if s % args.log_every == 0 or s == args.steps - 1:
             tok_s = args.batch * args.seq / max(dur, 1e-9)
-            print(f"step {s:5d} loss {float(m['loss']):.4f} "
+            print(f"step {s:5d} loss {loss:.4f} "
                   f"gnorm {float(m['grad_norm']):.3f} {tok_s:,.0f} tok/s")
         if ckpt and (s + 1) % args.ckpt_every == 0:
             ckpt.save({"params": params, "opt": opt}, s + 1)
@@ -82,8 +88,14 @@ def main(argv=None) -> int:
         ckpt.save({"params": params, "opt": opt}, args.steps)
         ckpt.wait()
     print(f"[train] done in {time.perf_counter()-t0:.1f}s")
+    if diverged:
+        print("[train] a loss was not finite")
+        return 1
     return 0
 
 
 if __name__ == "__main__":
+    from repro.launch.device import enable_compile_cache
+
+    enable_compile_cache()
     raise SystemExit(main())

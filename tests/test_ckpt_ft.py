@@ -99,7 +99,7 @@ def test_compressed_psum_in_shard_map():
     devs = jax.devices()
     if len(devs) < 1:
         pytest.skip("no devices")
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     mesh = Mesh(np.array(devs[:1]), ("d",))
@@ -174,7 +174,7 @@ def test_compressed_psum_int8_wire_dtype():
     import re
 
     import numpy as np
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     mesh = Mesh(np.array(jax.devices()[:1]), ("d",))
