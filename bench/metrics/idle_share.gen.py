@@ -1,0 +1,4 @@
+"""Percent of the traced window in which no op ran on the device. Moves
+tokens_per_s."""
+
+from bench.readers import idle_share as read  # noqa: F401
