@@ -295,7 +295,7 @@ def probe_kernels(service, cfg, *, max_batch: int, bucket: int,
                   prompt_len: int, reps: int = 20) -> None:
     """Eager dispatch calls at the serving shapes so the obs snapshot's
     ``dispatch_execute_seconds`` histograms carry real per-call samples for
-    both hot paths (in-model dispatches record at trace time only)."""
+    both hot paths (in-model dispatches run under jit and record nothing)."""
     K, G, hd = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads, cfg.hd
     BH = max_batch * K
     args = init_decode_attention(BH, G, bucket, hd)

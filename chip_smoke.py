@@ -153,16 +153,20 @@ def phase_serving(*, reduced: bool = False, batch: int = 4,
         raise PhaseFailed(f"serve exit code {rc}")
 
     tel = svc.telemetry()
+    # the kernels run inside jitted programs, where the execute wrapper
+    # records nothing: count the call sites that dispatch resolved instead
+    requests = svc.metrics.snapshot()["counters"]
     kernels = {}
     for kernel in ("flash_attention", "decode_attention"):
         impls = sorted({str(e["config"].get("impl")) for e in tel["executables"]
                         if e["kernel"] == kernel})
-        calls = sum(r["count"] for r in tel["execute_latency"]
-                    if r["kernel"] == kernel)
+        calls = int(sum(c["value"] for c in requests
+                        if c["name"] == "dispatch_requests_total"
+                        and c["labels"].get("kernel") == kernel))
         kernels[kernel] = {"impl": impls, "calls": calls}
         if impls != ["pallas"] or calls == 0:
-            raise PhaseFailed(f"{kernel} ran as {impls} ({calls} call(s)), "
-                              "not the Pallas kernel")
+            raise PhaseFailed(f"{kernel} ran as {impls} ({calls} dispatched "
+                              "call(s)), not the Pallas kernel")
 
     cfg = get_reduced(ARCH) if reduced else get_config(ARCH)
     params = init_params(cfg, jax.random.PRNGKey(seed))

@@ -41,7 +41,7 @@ from repro.dispatch.store import TuningStore
 from repro.guard.faults import fault_point
 from repro.kernels.util import default_target
 from repro.obs.metrics import get_registry, summarize_histograms
-from repro.obs.trace import get_tracer
+from repro.obs.trace import get_tracer, install_jax_hooks
 
 __all__ = ["DispatchService", "dispatch", "call", "get_service", "configure"]
 
@@ -62,6 +62,7 @@ class DispatchService:
         metrics=None,
     ):
         self.store = store
+        install_jax_hooks()   # compile events land under the span that caused them
         # repro.obs registry: per-signature execute-latency histograms and
         # request counters. Recording is shard-local (lock-free), so the
         # fast-hit path's one-lock contract holds with metrics enabled.
@@ -189,11 +190,7 @@ class DispatchService:
                                    target=self.target)
             if not verdict.ok:
                 if self.store is not None and res.exact:
-                    with tracer.span("dispatch.quarantine", kernel=kernel,
-                                     signature=sig_key,
-                                     reason=verdict.reason()):
-                        self.store.quarantine(res.record,
-                                              reason=verdict.reason())
+                    self.store.quarantine(res.record, reason=verdict.reason())
                 res = None
                 config = spec.default_config(self.target)
                 key = fast_key + (config_key(config),)
@@ -218,10 +215,7 @@ class DispatchService:
                 # this shape (e.g. an indivisible block), and quarantining it
                 # would destroy a config that is valid where it was tuned
                 if self.store is not None and res.exact:
-                    with tracer.span("dispatch.quarantine", kernel=kernel,
-                                     signature=sig_key):
-                        self.store.quarantine(res.record,
-                                              reason="build_failed")
+                    self.store.quarantine(res.record, reason="build_failed")
                 built, res = None, None
                 config = spec.default_config(self.target)
                 key = fast_key + (config_key(config),)
@@ -269,10 +263,16 @@ class DispatchService:
     def _instrument_execute(self, fn: Callable, kernel: str, sig_key: str,
                             *, sig=None, config=None,
                             static_kw=None) -> Callable:
-        """Wrap an executable so every call records into the per-signature
-        execute-latency histogram (and a trace span when tracing is on).
-        The wrapper is what the executable cache stores, so the identity
-        contract (repeat dispatch returns the same object) is unchanged.
+        """Wrap an executable so every eager call records into the
+        per-signature execute-latency histogram and a ``dispatch.execute``
+        span. The wrapper is what the executable cache stores, so the
+        identity contract (repeat dispatch returns the same object) is
+        unchanged.
+
+        A call under a jit trace (a kernel inside the serve step or the
+        prefill scans) records neither: its Python seconds are trace time,
+        and the kernel's device time is in the profiler's trace under the
+        kernel's name.
 
         On asynchronous backends this times dispatch-to-return as the caller
         observes it — the same quantity a serving loop's own latency sees;
@@ -284,7 +284,8 @@ class DispatchService:
         metrics, backend = self.metrics, self.backend
 
         def timed(*a, **kw):
-            tracer = get_tracer()
+            if any(isinstance(x, jax.core.Tracer) for x in a):
+                return fn(*a, **kw)
             guard = self._guard
             mode = (guard.shadow_mode(kernel, sig_key)
                     if guard is not None else None)
@@ -292,16 +293,10 @@ class DispatchService:
             try:
                 fault_point("dispatch.latency", kernel=kernel,
                             signature=sig_key)
-                if tracer.enabled:
-                    with tracer.span("dispatch.execute", kernel=kernel,
-                                     signature=sig_key):
-                        out = fn(*a, **kw)
-                else:
+                with get_tracer().span("dispatch.execute", kernel=kernel,
+                                       signature=sig_key):
                     out = fn(*a, **kw)
-                if mode is not None and not any(
-                        isinstance(x, jax.core.Tracer) for x in a):
-                    # skipped under jit tracing: a trace-time "latency" is
-                    # meaningless and must not be told into the store
+                if mode is not None:
                     jax.block_until_ready(out)
                     guard.on_shadow(kernel, sig, config, static_kw, a,
                                     time.perf_counter() - t0, mode)
@@ -465,7 +460,8 @@ class DispatchService:
 
     # -- generic executable cache (serving integration) --------------------------
 
-    def jit_cached(self, name: str, fn: Callable) -> Callable:
+    def jit_cached(self, name: str, fn: Callable, *, span: str | None = None,
+                   span_attrs: Callable[..., dict] | None = None) -> Callable:
         """Cache-and-jit an arbitrary callable under a stable name, sharing
         the service's executable cache and hit/miss counters. Used by the
         serving step so repeated ``make_serve_step`` calls for the same model
@@ -474,7 +470,11 @@ class DispatchService:
         Returns a stable proxy, not the jitted function itself: when
         :meth:`invalidate` drops the compiled entry (a kernel config hot
         swap), every held reference transparently re-traces against the new
-        configs on its next call instead of serving stale executables."""
+        configs on its next call instead of serving stale executables.
+
+        With ``span``, each call through the proxy is a ``repro.obs`` span
+        of that name (attributes from ``span_attrs(*args)``): it times the
+        launch, and holds any retrace or compile the call causes."""
         key = ("__fn__", name, (), ())
         with self._lock:
             self._fn_src.setdefault(key, fn)
@@ -490,11 +490,20 @@ class DispatchService:
         with self._lock:
             proxy = self._fn_proxy.get(key)
             if proxy is None:
-                proxy = self._fn_proxy[key] = self._make_fn_proxy(key)
+                proxy = self._fn_proxy[key] = self._make_fn_proxy(
+                    key, span, span_attrs)
         return proxy
 
-    def _make_fn_proxy(self, key: tuple) -> Callable:
+    def _make_fn_proxy(self, key: tuple, span: str | None = None,
+                       span_attrs: Callable[..., dict] | None = None) -> Callable:
         def proxy(*args, **kw):
+            if span is None:
+                return run(*args, **kw)
+            attrs = span_attrs(*args, **kw) if span_attrs is not None else {}
+            with get_tracer().span(span, **attrs):
+                return run(*args, **kw)
+
+        def run(*args, **kw):
             with self._lock:
                 fn = self._exec.get(key)
             if fn is None:  # invalidated: rebuild from source

@@ -109,6 +109,7 @@ def covariance(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
+        name="covariance",
         interpret=interpret,
     )(dp, dp, mp, mp)
     return unpad(out, (M, M))

@@ -164,6 +164,7 @@ def decode_attention(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
+        name="decode_attention",
         interpret=interpret,
     )(cpp, qp, kp, vp)
     return out[:BH]

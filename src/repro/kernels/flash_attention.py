@@ -115,6 +115,7 @@ def flash_attention(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
+        name="flash_attention",
         interpret=interpret,
     )(qp, kp, vp)
     return out[:, :Sq, :]

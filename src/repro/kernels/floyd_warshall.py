@@ -105,6 +105,7 @@ def minplus_update(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")
         ),
+        name="floyd_warshall",
         interpret=interpret,
     )(Dp, Ap, Bp)
     return out[:n, :m]

@@ -109,6 +109,7 @@ def heat3d_step(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)
         ),
+        name="heat3d",
         interpret=interpret,
     )(Ap, Ap, Ap)
     return out[:n0]

@@ -111,6 +111,7 @@ def tiled_matmul(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
+        name="matmul",
     )
     if pack:
         out = pl.pallas_call(

@@ -127,6 +127,7 @@ def syr2k(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
+        name="syr2k",
         interpret=interpret,
     )(Cp, Ap, Bp, Bp, Ap)
     return unpad(out, (N, N))

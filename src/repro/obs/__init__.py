@@ -7,11 +7,15 @@ Three layers, each usable alone:
   boundaries, so histograms merge deterministically across threads, processes,
   and hosts. Recording is lock-free (per-thread shards); folding happens only
   at snapshot time.
-* :mod:`repro.obs.trace` — span-based tracing writing append-only
-  Chrome-trace-event JSONL (one event per line). Off by default; enable with
-  ``configure_tracer(path)`` or the ``REPRO_TRACE=path`` environment
-  variable. ``repro-obs summarize --perfetto out.json`` wraps the JSONL into
-  a Perfetto-loadable ``{"traceEvents": [...]}`` file.
+* :mod:`repro.obs.trace` — spans with parent ids on the
+  ``perf_counter_ns`` clock, kept in a bounded in-memory ring (on by
+  default; :func:`recorded_spans`), mirrored into the JAX profiler's trace
+  while a session collects, with JAX's trace/lower/compile/cache-load
+  events recorded as child spans of the span that caused them. A
+  Chrome-trace-event JSONL file is written as well when
+  ``configure_tracer(path)`` or ``REPRO_TRACE=path`` asks for it;
+  ``repro-obs summarize --perfetto out.json`` wraps the JSONL into a
+  Perfetto-loadable ``{"traceEvents": [...]}`` file.
 * :mod:`repro.obs.export` — JSONL snapshot writer, Prometheus text
   exposition, and the stdlib-``http.server`` :class:`ObsServer` serving
   ``/metrics`` + ``/snapshot``.
@@ -34,10 +38,14 @@ from repro.obs.metrics import (
 )
 from repro.obs.trace import (
     NULL_TRACER,
+    SpanRecord,
     Tracer,
     configure_tracer,
+    dump_recorded,
     export_chrome_trace,
     get_tracer,
+    install_jax_hooks,
+    recorded_spans,
     span,
     validate_trace,
 )
@@ -62,6 +70,10 @@ __all__ = [
     "configure_tracer",
     "get_tracer",
     "span",
+    "SpanRecord",
+    "recorded_spans",
+    "dump_recorded",
+    "install_jax_hooks",
     "validate_trace",
     "export_chrome_trace",
     "ObsServer",

@@ -1,99 +1,233 @@
-"""Span tracing: append-only Chrome-trace-event JSONL.
+"""Span tracing: an in-memory ring of spans, mirrored into the JAX
+profiler's trace while one is collected, and optionally appended to a
+Chrome-trace-event JSONL file.
 
-Each line of the trace file is one Chrome trace event object (complete
-``"ph": "X"`` spans with microsecond ``ts``/``dur``, ``"i"`` instants, and
-``"M"`` metadata), so the file is simultaneously valid JSONL — crash-safe,
-torn-tail tolerant via :mod:`repro.core.jsonl`, greppable line by line — and
-trivially convertible to a Perfetto/``chrome://tracing``-loadable
-``{"traceEvents": [...]}`` JSON via :func:`export_chrome_trace` (or
-``repro-obs summarize --perfetto out.json``).
+Every span (``with span("serve.prefill", batch=1): ...``) records its name,
+a span id, its parent's id (the innermost span open on the same thread), its
+start and end as ``time.perf_counter_ns()``, its thread and its scalar
+attributes into a bounded process-wide :class:`SpanRing` (65,536 spans, on
+by default); what the ring drops is counted in ``obs_spans_dropped_total``.
+:func:`recorded_spans` reads the ring and :func:`dump_recorded` writes it out
+as Chrome-trace JSONL.
 
-Tracing is off by default: :func:`get_tracer` returns :data:`NULL_TRACER`
-(whose ``span()`` hands back a shared no-op context manager, so instrumented
-hot paths pay one attribute check) unless :func:`configure_tracer` was called
-or the ``REPRO_TRACE=path`` environment variable names a trace file. One
-timeline covers every instrumented layer — campaign ask/evaluate/tell,
-database checkpoints, dispatch lookup/build/execute/quarantine, background
-tuner campaigns/publishes, fleet pull/merge/push — because they all write
-through the same process tracer with per-thread ``tid``.
+While a JAX profiler session is collecting, each span also enters a
+``jax.profiler.TraceAnnotation`` of its name, so it sits on the host plane of
+the ``.xplane.pb`` beside the device's ops. Outside a session no annotation
+is entered. :func:`install_jax_hooks` (called by ``repro.serve`` and
+``repro.dispatch``, so this module imports without jax) wires the profiler
+check and a ``jax.monitoring`` listener that records each trace, lowering,
+backend compile and persistent-cache load as a child span (``jax.trace``,
+``jax.lower``, ``jax.compile``, ``jax.cache_load``) of the innermost open
+span, and counts them in ``jax_compile_events_total{event, span}`` and
+``jax_compile_seconds{event, span}``.
+
+The file tracer is off by default: :func:`get_tracer` returns
+:data:`NULL_TRACER`, whose spans go to the ring alone, unless
+:func:`configure_tracer` was called or the ``REPRO_TRACE=path`` environment
+variable names a trace file. Each line of that file is one Chrome trace
+event (complete ``"ph": "X"`` spans with microsecond ``ts``/``dur``, ``"i"``
+instants, ``"M"`` metadata), so it is valid JSONL — crash-safe, torn-tail
+tolerant via :mod:`repro.core.jsonl` — and converts to a
+Perfetto/``chrome://tracing``-loadable ``{"traceEvents": [...]}`` JSON via
+:func:`export_chrome_trace` (or ``repro-obs summarize --perfetto out.json``).
 """
 
 from __future__ import annotations
 
+import collections
+import itertools
 import json
 import os
 import threading
 import time
-from typing import Any, Iterator
+from typing import Iterator, NamedTuple
 
 from repro.core.jsonl import repair_torn_tail
+from repro.obs.metrics import get_registry
 
 __all__ = [
     "Tracer",
     "NULL_TRACER",
+    "SpanRecord",
+    "SpanRing",
     "get_tracer",
     "configure_tracer",
     "span",
     "instant",
+    "recorded_spans",
+    "dump_recorded",
+    "get_span_ring",
+    "set_span_ring",
+    "install_jax_hooks",
     "iter_trace",
     "validate_trace",
     "export_chrome_trace",
 ]
 
 TRACE_ENV = "REPRO_TRACE"
+RING_SPANS = 65536
+
+# the Chrome-trace timestamps of this process: wall-clock microseconds,
+# advanced by perf_counter so that traces of one host align across processes
+_WALL_NS0 = time.time_ns()
+_PERF_NS0 = time.perf_counter_ns()
+
+
+def _chrome_us(t_ns: int) -> int:
+    return (_WALL_NS0 + t_ns - _PERF_NS0) // 1000
+
+
+class SpanRecord(NamedTuple):
+    """One finished span. ``parent`` is 0 for a span opened with none open
+    on its thread; times are ``time.perf_counter_ns()``."""
+
+    id: int
+    parent: int
+    name: str
+    start_ns: int
+    end_ns: int
+    tid: int
+    attrs: dict
+
+
+class SpanRing:
+    """The last ``maxlen`` finished spans, oldest (by end) first. Appends
+    take no lock (``deque.append`` is atomic); a span pushed out of a full
+    ring is counted in ``dropped`` and in ``obs_spans_dropped_total``. It
+    holds finished ``_Span`` objects and ``SpanRecord`` tuples, and reads
+    both as ``SpanRecord``."""
+
+    def __init__(self, maxlen: int = RING_SPANS):
+        self.maxlen = int(maxlen)
+        self.dropped = 0
+        self.buf: collections.deque = collections.deque(maxlen=self.maxlen)
+
+    def append(self, rec) -> None:
+        if len(self.buf) == self.maxlen:
+            self.drop()
+        self.buf.append(rec)
+
+    def drop(self) -> None:
+        self.dropped += 1
+        get_registry().add("obs_spans_dropped_total")
+
+    def spans(self) -> list[SpanRecord]:
+        return [r.record() if isinstance(r, _Span) else SpanRecord(*r)
+                for r in list(self.buf)]
+
+
+class _Stack(list):
+    """One thread's open spans, innermost last, tagged with the thread."""
+
+    __slots__ = ("tid",)
+
+
+_ring = SpanRing()
+_ids = itertools.count(1)
+_local = threading.local()
+# set by install_jax_hooks(): the profiler's "is a session collecting"
+# check and its host-span annotation
+_profiling = None
+_annotation = None
+
+
+def get_span_ring() -> SpanRing:
+    return _ring
+
+
+def set_span_ring(ring: SpanRing) -> SpanRing:
+    """Swap the process ring (tests use this for isolation); returns the
+    one it replaces."""
+    global _ring
+    old, _ring = _ring, ring
+    return old
+
+
+def recorded_spans() -> list[SpanRecord]:
+    """The spans in the process ring, oldest (by end) first."""
+    return _ring.spans()
+
+
+def _open_spans() -> _Stack:
+    """This thread's open spans (``_local.stack``)."""
+    try:
+        return _local.stack
+    except AttributeError:
+        stack = _local.stack = _Stack()
+        stack.tid = threading.get_ident()
+        return stack
+
+
+def _chrome_event(name, t0, t1, tid, attrs, pid) -> dict:
+    ts = _chrome_us(t0)
+    ev = {"name": name, "cat": "repro", "ph": "X", "ts": ts,
+          "dur": max(0, _chrome_us(t1) - ts), "pid": pid, "tid": tid}
+    if attrs:
+        ev["args"] = attrs
+    return ev
 
 
 class _Span:
-    __slots__ = ("_tracer", "_name", "_attrs", "_t0")
+    # the hot path of every instrumented layer: plain attribute work, one
+    # clock read at each end, and the finished span itself goes to the ring
+    __slots__ = ("name", "attrs", "file", "id", "parent", "t0", "t1", "ann",
+                 "stack")
 
-    def __init__(self, tracer: "Tracer", name: str, attrs: dict):
-        self._tracer = tracer
-        self._name = name
-        self._attrs = attrs
+    def __init__(self, name: str, attrs: dict, file: "Tracer | None"):
+        self.name = name
+        self.attrs = attrs
+        self.file = file
 
     def __enter__(self) -> "_Span":
-        self._t0 = self._tracer._now_us()
+        try:
+            stack = _local.stack
+        except AttributeError:
+            stack = _open_spans()
+        self.stack = stack
+        self.parent = stack[-1].id if stack else 0
+        self.id = next(_ids)
+        stack.append(self)
+        if _profiling is not None and _profiling():
+            self.ann = _annotation(self.name)
+            self.ann.__enter__()
+        else:
+            self.ann = None
+        self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
-        t1 = self._tracer._now_us()
-        ev = {
-            "name": self._name,
-            "cat": "repro",
-            "ph": "X",
-            "ts": self._t0,
-            "dur": max(0, t1 - self._t0),
-            "pid": os.getpid(),
-            "tid": threading.get_ident(),
-        }
-        if self._attrs:
-            ev["args"] = self._attrs
+        self.t1 = time.perf_counter_ns()
+        if self.ann is not None:
+            self.ann.__exit__(None, None, None)
+            self.ann = None
+        stack = self.stack
+        if stack[-1] is self:
+            stack.pop()
+        else:
+            stack.remove(self)
         if exc_type is not None:
-            ev.setdefault("args", {})["error"] = exc_type.__name__
-        self._tracer.emit(ev)
+            self.attrs = dict(self.attrs, error=exc_type.__name__)
+        ring = _ring
+        if len(ring.buf) == ring.maxlen:
+            ring.drop()
+        ring.buf.append(self)
+        if self.file is not None:
+            self.file.emit(_chrome_event(self.name, self.t0, self.t1,
+                                         stack.tid, self.attrs, self.file._pid))
 
-
-class _NullSpan:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return None
-
-
-_NULL_SPAN = _NullSpan()
+    def record(self) -> SpanRecord:
+        return SpanRecord(self.id, self.parent, self.name, self.t0, self.t1,
+                          self.stack.tid, self.attrs)
 
 
 class NullTracer:
-    """The disabled tracer: every operation is a no-op."""
+    """No trace file: spans go to the ring (and the profiler) alone."""
 
     enabled = False
     path = None
 
-    def span(self, name: str, **attrs) -> _NullSpan:
-        return _NULL_SPAN
+    def span(self, name: str, **attrs) -> _Span:
+        return _Span(name, attrs, None)
 
     def instant(self, name: str, **attrs) -> None:
         pass
@@ -109,9 +243,8 @@ NULL_TRACER = NullTracer()
 
 
 class Tracer:
-    """Appends one trace event per line to ``path``. Thread-safe (one lock
-    around the file write); timestamps are wall-clock-anchored microseconds
-    advanced by ``perf_counter`` so same-host traces align across processes."""
+    """Appends one trace event per line to ``path`` (spans also go to the
+    ring). Thread-safe: one lock around the file write."""
 
     enabled = True
 
@@ -123,22 +256,19 @@ class Tracer:
         self.path = path
         self._f = open(path, "a")
         self._lock = threading.Lock()
-        self._wall_us0 = time.time_ns() // 1000
-        self._perf0 = time.perf_counter()
+        self._pid = os.getpid()
         if process_name:
-            self.emit({"name": "process_name", "ph": "M", "ts": self._wall_us0,
-                       "pid": os.getpid(), "tid": 0,
+            self.emit({"name": "process_name", "ph": "M",
+                       "ts": _chrome_us(time.perf_counter_ns()),
+                       "pid": self._pid, "tid": 0,
                        "args": {"name": process_name}})
 
-    def _now_us(self) -> int:
-        return self._wall_us0 + int((time.perf_counter() - self._perf0) * 1e6)
-
     def span(self, name: str, **attrs) -> _Span:
-        return _Span(self, name, attrs)
+        return _Span(name, attrs, self)
 
     def instant(self, name: str, **attrs) -> None:
         ev = {"name": name, "cat": "repro", "ph": "i", "s": "t",
-              "ts": self._now_us(), "pid": os.getpid(),
+              "ts": _chrome_us(time.perf_counter_ns()), "pid": self._pid,
               "tid": threading.get_ident()}
         if attrs:
             ev["args"] = attrs
@@ -167,7 +297,7 @@ _tracer_lock = threading.Lock()
 
 def get_tracer() -> "Tracer | NullTracer":
     """The process tracer: configured one, else ``REPRO_TRACE`` env
-    activation, else the shared no-op."""
+    activation, else the ring-only :data:`NULL_TRACER`."""
     global _tracer
     t = _tracer
     if t is not None:
@@ -197,13 +327,83 @@ def configure_tracer(path: "str | Tracer | None",
 
 
 def span(name: str, **attrs):
-    """``with obs.span("campaign.ask", learner="RF"): ...`` through the
-    process tracer (no-op unless tracing is enabled)."""
-    return get_tracer().span(name, **attrs)
+    """``with obs.span("campaign.ask", learner="RF"): ...`` into the ring,
+    and into the trace file when one is configured."""
+    t = _tracer
+    if t is None:
+        t = get_tracer()
+    return _Span(name, attrs, t if t.enabled else None)
 
 
 def instant(name: str, **attrs) -> None:
     get_tracer().instant(name, **attrs)
+
+
+def dump_recorded(path: str) -> int:
+    """Write the ring as Chrome-trace JSONL (what the file tracer writes,
+    span and parent ids under ``args``). Returns the number of spans."""
+    spans = recorded_spans()
+    pid = os.getpid()
+    parent = os.path.dirname(path)
+    if parent:
+        os.makedirs(parent, exist_ok=True)
+    with open(path, "w") as f:
+        for s in spans:
+            ev = _chrome_event(s.name, s.start_ns, s.end_ns, s.tid,
+                               dict(s.attrs, span_id=s.id, parent_id=s.parent),
+                               pid)
+            f.write(json.dumps(ev, default=str) + "\n")
+    return len(spans)
+
+
+# -- JAX: profiler mirroring and compile events ---------------------------------
+
+JAX_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+    "/jax/core/compile/backend_compile_duration": "jax.compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "jax.cache_load",
+}
+_jax_lock = threading.Lock()
+
+
+def _on_jax_duration(event: str, secs: float, **kw) -> None:
+    """A JAX compile-path event, reported as it ends on the thread that
+    paid for it: a child span of the innermost open span, and the
+    ``jax_compile_*`` counters labelled with that span's name."""
+    name = JAX_EVENTS.get(event)
+    if name is None:
+        return
+    t1 = time.perf_counter_ns()
+    t0 = t1 - int(secs * 1e9)
+    stack = _open_spans()
+    owner = stack[-1] if stack else None
+    attrs = {"seconds": secs}
+    _ring.append(SpanRecord(next(_ids), owner.id if owner is not None else 0,
+                            name, t0, t1, stack.tid, attrs))
+    tracer = get_tracer()
+    if tracer.enabled:
+        tracer.emit(_chrome_event(name, t0, t1, stack.tid, attrs, tracer._pid))
+    where = owner.name if owner is not None else "none"
+    reg = get_registry()
+    reg.add("jax_compile_events_total", event=name, span=where)
+    reg.add("jax_compile_seconds", secs, event=name, span=where)
+
+
+def install_jax_hooks() -> None:
+    """Once per process: mirror spans into the JAX profiler's trace while a
+    session collects, and record JAX's compile-path events as spans."""
+    global _profiling, _annotation
+    if _profiling is not None:
+        return
+    with _jax_lock:
+        if _profiling is not None:
+            return
+        import jax
+
+        jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+        _annotation = jax.profiler.TraceAnnotation
+        _profiling = jax.profiler.TraceAnnotation.is_enabled
 
 
 # -- validation / export ---------------------------------------------------------

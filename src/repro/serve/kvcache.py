@@ -29,6 +29,7 @@ import jax.numpy as jnp
 
 from repro.models.common import ArchConfig
 from repro.models.model import init_cache
+from repro.obs.trace import install_jax_hooks, span
 
 __all__ = [
     "init_cache", "cache_bytes_per_token", "cache_bytes", "PagedKVCache",
@@ -71,7 +72,10 @@ class PagedKVCache:
     Only the GQA attention families qualify: MLA keeps its latent cache,
     SSM state is O(1), and ring-buffer (sliding-window) caches already
     allocate O(window). The windowless restriction is the same static gate
-    the decode dispatch route uses (``blocks.attn_layer_decode``)."""
+    the decode dispatch route uses (``blocks.attn_layer_decode``).
+
+    ``admit``, ``view`` and ``writeback`` are ``repro.obs`` spans
+    (``kv.admit``, ``kv.view``, ``kv.writeback``) timed to their enqueue."""
 
     def __init__(self, cfg: ArchConfig, max_batch: int, max_len: int, *,
                  page_size: int = 128, dtype=None):
@@ -83,6 +87,7 @@ class PagedKVCache:
                              "(their ring cache is already O(window))")
         if page_size <= 0:
             raise ValueError(f"page_size must be positive, got {page_size}")
+        install_jax_hooks()
         self.cfg = cfg
         self.max_batch = int(max_batch)
         self.page_size = int(page_size)
@@ -111,7 +116,8 @@ class PagedKVCache:
             idx = (0,) * (buf.ndim - 4) + (slot, 0, 0, 0)
             return jax.lax.dynamic_update_slice(buf, new.astype(buf.dtype), idx)
 
-        self.buf = jax.tree_util.tree_map(insert, self.buf, prefilled)
+        with span("kv.admit", slot=slot):
+            self.buf = jax.tree_util.tree_map(insert, self.buf, prefilled)
         self.pos[slot] = prompt_len - 1
 
     def release(self, slot: int) -> None:
@@ -134,9 +140,10 @@ class PagedKVCache:
         idx = np.asarray(slots, np.int32)
         # stacked per-layer leaves are (L, B, S, K, hd); un-stacked singleton
         # sites (e.g. a moe arch's leading dense layer) are (B, S, K, hd)
-        return jax.tree_util.tree_map(
-            lambda a: a[:, idx, :bucket] if a.ndim == 5 else a[idx, :bucket],
-            self.buf)
+        with span("kv.view", slots=len(idx), bucket=bucket):
+            return jax.tree_util.tree_map(
+                lambda a: a[:, idx, :bucket] if a.ndim == 5 else a[idx, :bucket],
+                self.buf)
 
     def writeback(self, slots, bucket: int, cache: dict) -> None:
         """Scatter a round's updated view back into the backing buffer."""
@@ -148,7 +155,8 @@ class PagedKVCache:
                 return buf.at[:, idx, :bucket].set(c)
             return buf.at[idx, :bucket].set(c)
 
-        self.buf = jax.tree_util.tree_map(put, self.buf, cache)
+        with span("kv.writeback", slots=len(idx), bucket=bucket):
+            self.buf = jax.tree_util.tree_map(put, self.buf, cache)
 
     def pos_vector(self, slots) -> jnp.ndarray:
         """(len(slots),) int32 per-sequence decode positions."""

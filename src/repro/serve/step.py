@@ -11,6 +11,7 @@ import jax.numpy as jnp
 
 from repro.models.common import ArchConfig
 from repro.models.model import decode_step, forward, init_cache
+from repro.obs.trace import install_jax_hooks, span
 
 __all__ = ["prefill", "greedy_decode", "make_serve_step"]
 
@@ -21,17 +22,26 @@ def prefill(params, batch, cfg: ArchConfig, max_len: int, service=None, **fw_kw)
     is a §Perf optimization). ``service`` routes the prompt forward's
     attention (tuned flash ``bq``/``bk``) and matmul call sites through
     :mod:`repro.dispatch` — this is where serving traffic finally meets the
-    tuning store."""
-    logits, _ = forward(params, batch, cfg, service=service, **fw_kw)
+    tuning store.
+
+    Spans (host time; device work is timed to its enqueue): ``serve.prefill``
+    around the call, ``serve.prefill.forward`` and ``serve.prefill.replay``
+    around its two halves. JAX's traces, lowerings and compiles land inside
+    them as ``jax.*`` child spans."""
+    install_jax_hooks()
     B, S = batch["tokens"].shape
-    cache = init_cache(cfg, B, max_len)
+    with span("serve.prefill", batch=B, prompt_len=S):
+        with span("serve.prefill.forward"):
+            logits, _ = forward(params, batch, cfg, service=service, **fw_kw)
+        cache = init_cache(cfg, B, max_len)
 
-    def body(cache, t):
-        _, cache = decode_step(params, cache, jax.lax.dynamic_slice_in_dim(
-            batch["tokens"], t, 1, axis=1), t, cfg, service=service)
-        return cache, None
+        def body(cache, t):
+            _, cache = decode_step(params, cache, jax.lax.dynamic_slice_in_dim(
+                batch["tokens"], t, 1, axis=1), t, cfg, service=service)
+            return cache, None
 
-    cache, _ = jax.lax.scan(body, cache, jnp.arange(S))
+        with span("serve.prefill.replay"):
+            cache, _ = jax.lax.scan(body, cache, jnp.arange(S))
     return logits, cache
 
 
@@ -43,7 +53,8 @@ def make_serve_step(cfg: ArchConfig, *, mla_absorb: bool = True, service=None):
     the same model config shares one jitted entry point — and the decode
     matmul call sites inside resolve tuned block shapes from the service's
     store, so its hit/miss counters cover serving traffic alongside kernel
-    dispatches."""
+    dispatches. Each call through the service's proxy is a ``serve.step``
+    span (attribute ``batch``): the host time to launch one step."""
 
     def serve_step(params, cache, token, pos):
         logits, cache = decode_step(params, cache, token, pos, cfg,
@@ -55,8 +66,13 @@ def make_serve_step(cfg: ArchConfig, *, mla_absorb: bool = True, service=None):
         # key on the full dataclass repr: two configs sharing a name (e.g. a
         # full model and its reduced() variant) must not share a closure
         return service.jit_cached(
-            f"serve_step/{cfg!r}/absorb={mla_absorb}", serve_step)
+            f"serve_step/{cfg!r}/absorb={mla_absorb}", serve_step,
+            span="serve.step", span_attrs=_step_attrs)
     return serve_step
+
+
+def _step_attrs(params, cache, token, pos) -> dict:
+    return {"batch": token.shape[0]}
 
 
 def greedy_decode(params, cfg: ArchConfig, prompt: jnp.ndarray, steps: int,

@@ -680,6 +680,36 @@ def test_telemetry_reports_execute_latency_quantiles():
     assert row["mean_sec"] > 0
 
 
+def test_kernel_under_jit_records_no_execute_latency():
+    """A dispatched kernel called inside a jitted function runs at trace
+    time only: it adds no ``dispatch_execute_seconds`` observation and no
+    ``dispatch.execute`` span. An eager call adds one of each."""
+    import jax
+
+    from repro.obs.metrics import MetricsRegistry, summarize_histograms
+    from repro.obs.trace import recorded_spans
+
+    svc = DispatchService(metrics=MetricsRegistry())
+    x = np.arange(4.0)
+    fn = svc.dispatch("toy_scale", x)
+
+    def observed():
+        rows = summarize_histograms(svc.metrics.snapshot(),
+                                    name="dispatch_execute_seconds")
+        return sum(r["count"] for r in rows)
+
+    def execute_spans():
+        return sum(s.name == "dispatch.execute" and s.attrs.get("kernel") == "toy_scale"
+                   for s in recorded_spans())
+
+    spans0 = execute_spans()
+    out = jax.jit(lambda v: fn(v) + 1.0)(x)
+    np.testing.assert_array_equal(np.asarray(out), x + 1.0)
+    assert observed() == 0 and execute_spans() == spans0
+    np.testing.assert_array_equal(np.asarray(fn(x)), x)
+    assert observed() == 1 and execute_spans() == spans0 + 1
+
+
 def test_optimizer_overhead_telemetry_flows_to_tuner(tmp_path):
     """Campaign.timings (ask/tell/wait seconds) aggregate into
     BackgroundTuner.stats — the CATBench-style first-class overhead metric."""

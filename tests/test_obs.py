@@ -1,12 +1,16 @@
 """repro.obs: lock-free sharded metrics folding to exact totals under
 concurrency, deterministic (associative + commutative) histogram merges,
-Prometheus text exposition, crash-tolerant Chrome-trace JSONL, the HTTP
-scrape endpoint, and the instrumentation hooks in dispatch / engine / fleet."""
+Prometheus text exposition, crash-tolerant Chrome-trace JSONL, the span
+ring with its parent ids, profiler mirroring and compile-event children,
+the HTTP scrape endpoint, and the instrumentation hooks in dispatch /
+engine / fleet."""
 
+import glob
 import json
 import math
 import os
 import threading
+import time
 import urllib.request
 
 import numpy as np
@@ -28,11 +32,18 @@ from repro.obs.metrics import (
     set_registry,
     summarize_histograms,
 )
+from repro.obs import trace as obs_trace
 from repro.obs.trace import (
+    SpanRing,
     Tracer,
     configure_tracer,
+    dump_recorded,
     export_chrome_trace,
     get_tracer,
+    install_jax_hooks,
+    recorded_spans,
+    set_span_ring,
+    span,
     validate_trace,
 )
 
@@ -54,6 +65,17 @@ def no_tracer():
     configure_tracer(None)
     yield
     configure_tracer(None)
+
+
+@pytest.fixture
+def ring():
+    """An empty span ring for the test; the process ring is restored after."""
+    fresh = SpanRing()
+    old = set_span_ring(fresh)
+    try:
+        yield fresh
+    finally:
+        set_span_ring(old)
 
 
 # ---------------------------------------------------------------------------
@@ -279,6 +301,159 @@ def test_env_var_activates_tracer(tmp_path, no_tracer, monkeypatch):
         pass
     configure_tracer(None)
     assert "via.env" in validate_trace(path)["names"]
+
+
+# ---------------------------------------------------------------------------
+# the span ring: parent ids, bound, profiler clock, compile events
+# ---------------------------------------------------------------------------
+
+
+def test_span_parents_follow_each_threads_own_stack(ring, no_tracer):
+    """A span's parent is the innermost span open on its own thread: two
+    threads nesting at the same time never adopt each other's spans."""
+    both_open = threading.Barrier(2, timeout=10)
+    ids = {}
+
+    def work(tag):
+        with span(f"{tag}.outer") as outer:
+            both_open.wait()
+            with span(f"{tag}.inner") as inner:
+                both_open.wait()
+            ids[tag] = (outer.id, inner.id)
+
+    threads = [threading.Thread(target=work, args=(t,)) for t in ("a", "b")]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=10)
+    assert not any(t.is_alive() for t in threads)
+    by_name = {s.name: s for s in recorded_spans()}
+    for tag in ("a", "b"):
+        outer, inner = by_name[f"{tag}.outer"], by_name[f"{tag}.inner"]
+        assert (outer.id, inner.id) == ids[tag]
+        assert outer.parent == 0 and inner.parent == outer.id
+        assert outer.tid == inner.tid
+        assert outer.start_ns <= inner.start_ns <= inner.end_ns <= outer.end_ns
+    assert by_name["a.outer"].tid != by_name["b.outer"].tid
+    # closed spans leave the stack: the next span on this thread is a root
+    with span("after"):
+        pass
+    assert recorded_spans()[-1].parent == 0
+
+
+def test_ring_keeps_the_newest_and_counts_what_it_drops(fresh_registry,
+                                                         no_tracer, tmp_path):
+    small = SpanRing(maxlen=4)
+    old = set_span_ring(small)
+    try:
+        for i in range(10):
+            with span("s", i=i):
+                pass
+        got = recorded_spans()
+    finally:
+        set_span_ring(old)
+    assert [s.attrs["i"] for s in got] == [6, 7, 8, 9]
+    assert small.dropped == 6
+    (c,) = [c for c in fresh_registry.snapshot()["counters"]
+            if c["name"] == "obs_spans_dropped_total"]
+    assert c["value"] == 6.0
+
+
+def test_dump_recorded_writes_loadable_chrome_jsonl(ring, no_tracer, tmp_path):
+    with span("outer", slot=3):
+        with span("inner"):
+            pass
+    path = str(tmp_path / "ring.jsonl")
+    assert dump_recorded(path) == 2
+    report = validate_trace(path)
+    assert report["ok"] and report["names"] == ["inner", "outer"]
+    events = {e["name"]: e for e in map(json.loads, open(path))}
+    assert events["inner"]["args"]["parent_id"] == events["outer"]["args"]["span_id"]
+    assert events["outer"]["args"]["slot"] == 3
+    assert export_chrome_trace(path, str(tmp_path / "ring.json")) == 2
+
+
+def test_fresh_jit_inside_a_span_records_compile_children(ring, fresh_registry,
+                                                          no_tracer):
+    """JAX's trace, lowering and backend-compile events of a fresh jit land
+    as child spans of the span open when they happened, and in the
+    ``jax_compile_*`` counters under that span's name."""
+    import jax
+    import jax.numpy as jnp
+
+    install_jax_hooks()
+    with span("test.compiling") as owner:
+        jax.jit(lambda x: x * 7.0 - 1.0)(jnp.arange(3.0)).block_until_ready()
+    spans = recorded_spans()
+    (own,) = [s for s in spans if s.name == "test.compiling"]
+    kids = [s for s in spans if s.parent == owner.id]
+    assert {"jax.trace", "jax.lower", "jax.compile"} <= {s.name for s in kids}
+    for s in kids:
+        assert s.attrs["seconds"] >= 0
+        # each event ends where it is reported, inside its owner (its start
+        # comes from JAX's own duration, on another clock: allow 1 ms)
+        assert own.start_ns - 1_000_000 <= s.start_ns <= s.end_ns <= own.end_ns
+    counters = {(c["name"], c["labels"]["event"], c["labels"]["span"]): c["value"]
+                for c in fresh_registry.snapshot()["counters"]
+                if c["name"].startswith("jax_compile_")}
+    for event in ("jax.trace", "jax.lower", "jax.compile"):
+        assert counters[("jax_compile_events_total", event, "test.compiling")] >= 1
+        assert counters[("jax_compile_seconds", event, "test.compiling")] >= 0
+
+
+def test_spans_sit_on_the_profiler_clock(ring, no_tracer, tmp_path):
+    """Under a profiler session each span is also a host annotation in the
+    xplane; its offset from an enclosing anchor annotation agrees with the
+    recorded ``perf_counter_ns`` offset within 100 us."""
+    import jax
+    from jax.profiler import ProfileData
+
+    install_jax_hooks()
+    with jax.profiler.trace(str(tmp_path)):
+        with jax.profiler.TraceAnnotation("test.anchor"):
+            anchor_ns = time.perf_counter_ns()
+            for i in range(4):
+                with span("test.outer", i=i):
+                    time.sleep(0.002)
+                    with span("test.inner"):
+                        time.sleep(0.001)
+    (pb,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    host = {}
+    for plane in ProfileData.from_file(pb).planes:
+        if plane.name.startswith("/host"):
+            for line in plane.lines:
+                for e in line.events:
+                    host.setdefault(e.name, []).append(e.start_ns)
+    (xp_anchor,) = host["test.anchor"]
+    for name in ("test.outer", "test.inner"):
+        xp = sorted(t - xp_anchor for t in host[name])
+        rec = sorted(s.start_ns - anchor_ns for s in recorded_spans() if s.name == name)
+        assert len(xp) == len(rec) == 4
+        assert max(abs(a - b) for a, b in zip(xp, rec)) < 100_000
+
+
+def test_no_annotation_is_entered_without_a_profiler_session(ring, no_tracer,
+                                                             monkeypatch, tmp_path):
+    import jax
+
+    install_jax_hooks()
+    entered = []
+    real = obs_trace._annotation
+
+    def counting(name):
+        entered.append(name)
+        return real(name)
+
+    monkeypatch.setattr(obs_trace, "_annotation", counting)
+    for _ in range(3):
+        with span("quiet"):
+            pass
+    assert entered == []
+    assert [s.name for s in recorded_spans()] == ["quiet"] * 3
+    with jax.profiler.trace(str(tmp_path)):
+        with span("loud"):
+            pass
+    assert entered == ["loud"]
 
 
 # ---------------------------------------------------------------------------

@@ -257,3 +257,46 @@ def test_greedy_decode_service_resolves_tuned_decode_record(tmp_path):
     assert svc.stats["store_exact"] >= 1
     assert svc.stats["build_failed"] == 0
     np.testing.assert_array_equal(np.asarray(toks), np.asarray(base))
+
+
+def test_serving_layers_record_their_spans():
+    """prefill, the dispatched serve step and the paged cache record the
+    spans the benchmark reads, nested as the calls are: the forward and the
+    replay under ``serve.prefill``, with their attributes."""
+    from repro.dispatch import DispatchService
+    from repro.obs import trace
+    from repro.serve import PagedKVCache
+
+    cfg = _cfg("qwen2-0.5b")
+    params = init_params(cfg, KEY)
+    svc = DispatchService()
+    pc = PagedKVCache(cfg, max_batch=2, max_len=16, page_size=8)
+    serve = make_serve_step(cfg, service=svc)
+    old = trace.set_span_ring(trace.SpanRing())
+    try:
+        toks = jax.random.randint(KEY, (1, 5), 0, cfg.vocab_size)
+        logits, cache = prefill(params, {"tokens": toks}, cfg, max_len=pc.alloc,
+                                service=svc)
+        pc.admit(1, cache, 5)
+        view = pc.view([0, 1], 8)
+        _, _, view = serve(params, view, jnp.zeros((2, 1), jnp.int32),
+                           jnp.asarray([0, 5], jnp.int32))
+        pc.writeback([0, 1], 8, view)
+        spans = trace.recorded_spans()
+    finally:
+        trace.set_span_ring(old)
+    by = {}
+    for s in spans:
+        by.setdefault(s.name, []).append(s)
+    (pre,) = by["serve.prefill"]
+    assert pre.attrs == {"batch": 1, "prompt_len": 5} and pre.parent == 0
+    for half in ("serve.prefill.forward", "serve.prefill.replay"):
+        (h,) = by[half]
+        assert h.parent == pre.id and pre.start_ns <= h.start_ns <= h.end_ns <= pre.end_ns
+    assert [s.attrs for s in by["kv.admit"]] == [{"slot": 1}]
+    assert [s.attrs for s in by["kv.view"]] == [{"slots": 2, "bucket": 8}]
+    assert [s.attrs for s in by["kv.writeback"]] == [{"slots": 2, "bucket": 8}]
+    (step,) = by["serve.step"]
+    assert step.attrs == {"batch": 2}
+    # the step's first call traced and compiled under its own span
+    assert any(s.parent == step.id for s in by["jax.compile"])
