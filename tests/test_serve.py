@@ -262,7 +262,9 @@ def test_greedy_decode_service_resolves_tuned_decode_record(tmp_path):
 def test_serving_layers_record_their_spans():
     """prefill, the dispatched serve step and the paged cache record the
     spans the benchmark reads, nested as the calls are: the forward and the
-    replay under ``serve.prefill``, with their attributes."""
+    replay under ``serve.prefill``, with their attributes. A second prefill
+    of the same shape runs the cached program: one ``serve.prefill`` with
+    nothing below it, and the first call's logits and cache bit for bit."""
     from repro.dispatch import DispatchService
     from repro.obs import trace
     from repro.serve import PagedKVCache
@@ -277,6 +279,8 @@ def test_serving_layers_record_their_spans():
         toks = jax.random.randint(KEY, (1, 5), 0, cfg.vocab_size)
         logits, cache = prefill(params, {"tokens": toks}, cfg, max_len=pc.alloc,
                                 service=svc)
+        logits2, cache2 = prefill(params, {"tokens": toks}, cfg, max_len=pc.alloc,
+                                  service=svc)
         pc.admit(1, cache, 5)
         view = pc.view([0, 1], 8)
         _, _, view = serve(params, view, jnp.zeros((2, 1), jnp.int32),
@@ -288,11 +292,17 @@ def test_serving_layers_record_their_spans():
     by = {}
     for s in spans:
         by.setdefault(s.name, []).append(s)
-    (pre,) = by["serve.prefill"]
-    assert pre.attrs == {"batch": 1, "prompt_len": 5} and pre.parent == 0
+    pre, again = sorted(by["serve.prefill"], key=lambda s: s.start_ns)
+    for p in (pre, again):
+        assert p.attrs == {"batch": 1, "prompt_len": 5} and p.parent == 0
     for half in ("serve.prefill.forward", "serve.prefill.replay"):
         (h,) = by[half]
         assert h.parent == pre.id and pre.start_ns <= h.start_ns <= h.end_ns <= pre.end_ns
+    # the same shape again: the cached executable, nothing traced or compiled
+    assert not any(s.parent == again.id for s in spans)
+    np.testing.assert_array_equal(np.asarray(logits2), np.asarray(logits))
+    for a, b in zip(jax.tree_util.tree_leaves(cache2), jax.tree_util.tree_leaves(cache)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     assert [s.attrs for s in by["kv.admit"]] == [{"slot": 1}]
     assert [s.attrs for s in by["kv.view"]] == [{"slots": 2, "bucket": 8}]
     assert [s.attrs for s in by["kv.writeback"]] == [{"slots": 2, "bucket": 8}]
@@ -300,3 +310,59 @@ def test_serving_layers_record_their_spans():
     assert step.attrs == {"batch": 2}
     # the step's first call traced and compiled under its own span
     assert any(s.parent == step.id for s in by["jax.compile"])
+
+
+def _prefill_counts(registry) -> tuple[float, float]:
+    counters = {c["name"]: c["value"] for c in registry.snapshot()["counters"]}
+    return (counters.get("serve_prefill_calls_total", 0.0),
+            counters.get("serve_prefill_traces_total", 0.0))
+
+
+@pytest.mark.parametrize("dispatched", [True, False], ids=["service", "no_service"])
+def test_prefill_traces_once_per_prompt_length(dispatched):
+    """Calls at three prompt lengths, twice each: six calls, three traces
+    (the counters ``/metrics`` shows)."""
+    from repro.dispatch import DispatchService
+    from repro.obs.metrics import MetricsRegistry, get_registry, set_registry
+
+    cfg = _cfg("qwen2-0.5b")
+    params = init_params(cfg, KEY)
+    svc = DispatchService() if dispatched else None
+    # a max_len no other test uses: the service-free program is process-wide
+    max_len = 29
+    old = set_registry(MetricsRegistry())
+    try:
+        for S in (3, 5, 7):
+            for _ in range(2):
+                toks = jax.random.randint(KEY, (1, S), 0, cfg.vocab_size)
+                prefill(params, {"tokens": toks}, cfg, max_len=max_len, service=svc)
+        assert _prefill_counts(get_registry()) == (6, 3)
+    finally:
+        set_registry(old)
+
+
+def test_prefill_retraces_once_after_invalidate():
+    """A hot swap (``DispatchService.invalidate``) makes the next prefill
+    retrace against the new configs, once; later calls reuse that trace."""
+    from repro.dispatch import DispatchService
+    from repro.obs.metrics import MetricsRegistry, get_registry, set_registry
+
+    cfg = _cfg("qwen2-0.5b")
+    params = init_params(cfg, KEY)
+    svc = DispatchService()
+    toks = jax.random.randint(KEY, (1, 4), 0, cfg.vocab_size)
+    old = set_registry(MetricsRegistry())
+    try:
+        def call():
+            return prefill(params, {"tokens": toks}, cfg, max_len=12, service=svc)
+
+        first, _ = call()
+        call()
+        assert _prefill_counts(get_registry()) == (2, 1)
+        svc.invalidate()
+        again, _ = call()
+        call()
+        assert _prefill_counts(get_registry()) == (4, 2)
+        np.testing.assert_array_equal(np.asarray(again), np.asarray(first))
+    finally:
+        set_registry(old)
