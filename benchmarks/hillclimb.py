@@ -30,7 +30,7 @@ import json      # noqa: E402
 HBM_BYTES = 16e9  # v5e per-chip HBM
 
 
-def knob_space(kind: str, is_moe: bool, seed: int = 1234):
+def knob_space(kind: str, seed: int = 1234):
     from repro.core.space import Categorical, ConfigurationSpace, Ordinal
 
     cs = ConfigurationSpace(seed=seed)
@@ -50,11 +50,6 @@ def knob_space(kind: str, is_moe: bool, seed: int = 1234):
             Categorical("attn_f32", (True, False), default=True),
             Categorical("mla_absorb", (True, False), default=True),
         ])
-    if is_moe:
-        cs.add_hyperparameters([
-            Ordinal("moe_group", (512, 1024, 2048, 4096, 8192), default=2048),
-            Ordinal("capacity_factor", (1.0, 1.25, 1.5, 2.0), default=1.25),
-        ])
     return cs
 
 
@@ -62,7 +57,7 @@ def config_to_knobs(config: dict) -> dict:
     knobs: dict = {}
     overrides: dict = {}
     for k, v in config.items():
-        if k in ("attn_f32", "moe_group", "capacity_factor"):
+        if k == "attn_f32":
             overrides[k] = v
         elif k == "accum":
             knobs["accum"] = int(v)
@@ -119,28 +114,21 @@ PROBES = [
      {"seq_parallel": True, "moment_dtype": "bfloat16", "accum": 4}),
     ("+ bf16 attention scores",
      {"seq_parallel": True, "moment_dtype": "bfloat16", "attn_f32": False}),
-    ("+ tight MoE dispatch (group 1024, cf 1.0)",
-     {"seq_parallel": True, "moment_dtype": "bfloat16",
-      "moe_group": 1024, "capacity_factor": 1.0}),
 ]
 
 
 def run_probe(arch: str, shape: str, out: str, multi_pod: bool = False):
     """Hypothesis -> change -> re-lower -> record, one compile per row."""
-    from repro.configs import get_config
     from repro.launch.mesh import make_production_mesh
 
     mesh = make_production_mesh(multi_pod=multi_pod)
-    cfg = get_config(arch)
     log: list = []
     ev = make_cell_evaluator(arch, shape, mesh, log)
-    space = knob_space("train", is_moe=cfg.n_experts > 0)
+    space = knob_space("train")
     default = space.default_configuration()
 
     rows = []
     for label, delta in PROBES:
-        if ("moe_group" in delta or "capacity_factor" in delta) and not cfg.n_experts:
-            continue
         config = dict(default)
         config.update({k: v for k, v in delta.items() if k in default})
         res = ev(config)
@@ -178,16 +166,15 @@ def run(arch: str, shape: str, evals: int, out: str, multi_pod: bool = False,
     keeps that many lower+compile evaluations in flight (each evaluation
     holds the GIL only between XLA calls, so compiles overlap well)."""
     import jax
-    from repro.configs import SHAPES, get_config
+    from repro.configs import SHAPES
     from repro.engine import Campaign
     from repro.launch.mesh import make_production_mesh
 
     mesh = make_production_mesh(multi_pod=multi_pod)
-    cfg = get_config(arch)
     kind = SHAPES[shape].kind
     log: list = []
     ev = make_cell_evaluator(arch, shape, mesh, log)
-    space = knob_space(kind, is_moe=cfg.n_experts > 0)
+    space = knob_space(kind)
 
     # paper-faithful baseline first: the space's defaults, warm-starting the
     # search so 'best' can never regress below the known default schedule

@@ -304,6 +304,40 @@ class MxuAlign(Rule):
         return f"MXU (8,128) alignment: {', '.join(self.names)} (tpu/cost)"
 
 
+class LaneBlocks(Rule):
+    """TPU-class targets: Mosaic refuses a block whose last dim is neither
+    a multiple of the 128-lane tile nor the whole array dim (the rule the
+    chip enforced on the first kernels brought up on it). ``axes`` maps
+    each lane-dim tile param to the dim index it tiles; tiles are taken
+    after the builder's clamp to the dim, and widened to the whole dim
+    where they do not divide it, as ``grouped_tiles`` does. Only the
+    Pallas impl has blocks."""
+
+    def __init__(self, axes: Mapping[str, int]):
+        self.axes = dict(axes)
+
+    def check(self, cfg, ctx):
+        if ctx.target not in ("tpu", "cost") or ctx.dims is None \
+                or cfg.get("impl", "pallas") != "pallas":
+            return
+        for name, di in self.axes.items():
+            v = cfg.get(name)
+            if not (_is_int(v) and v > 0) or di >= len(ctx.dims):
+                continue
+            n = int(ctx.dims[di])
+            t = min(v, n)
+            t = t if n % t == 0 else n
+            if t % 128 and t != n:
+                yield Finding(
+                    f"lane_misaligned:{name}", ERROR,
+                    f"{name}={v} gives a {t}-wide block of a {n}-wide dim: "
+                    f"neither a multiple of 128 nor the whole dim", name)
+
+    def describe(self):
+        return (f"lane-dim blocks {', '.join(self.axes)} are multiples of "
+                f"128 or the whole dim (tpu/cost)")
+
+
 class LcmPadding(Rule):
     """Host syr2k/covariance pad the square dim up to a multiple of
     ``lcm(bi, bj)`` (after the builder's ``min(tile, dim)`` clamp); mixed
@@ -430,6 +464,14 @@ KERNEL_RULES: dict[str, tuple[Rule, ...]] = {
         VmemBudget(),
         MxuAlign("bk"),
         GridBound({"hg": 0, "bk": 2}),
+    ),
+    "grouped_matmul": (
+        ChoiceIn("impl", ("pallas", "xla")),
+        PositiveIntTiles("tm", "tk", "tn"),
+        LaneBlocks({"tk": 1, "tn": 2}),
+        VmemBudget(),
+        MxuAlign("tm"),
+        GridBound({"tm": 0, "tn": 2, "tk": 1}),
     ),
     "matmul": (
         PositiveIntTiles("bm", "bn", "bk"),

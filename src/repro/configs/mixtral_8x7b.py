@@ -1,6 +1,6 @@
 """mixtral-8x7b [moe] — 32L d_model=4096 32H (GQA kv=8) d_ff=14336
-vocab=32000, 8 experts top-2, sliding-window attention.
-[arXiv:2401.04088; hf]"""
+vocab=32000, 8 experts top-2, dense attention over a 32k context
+(the published ``sliding_window`` is null). [arXiv:2401.04088; hf]"""
 
 from repro.models.common import ArchConfig
 
@@ -15,12 +15,12 @@ CONFIG = ArchConfig(
     d_ff=14336,
     moe_d_ff=14336,
     vocab_size=32000,
-    sliding_window=4096,
+    sliding_window=None,
     n_experts=8,
     top_k=2,
     rope_theta=1.0e6,
+    rms_eps=1.0e-5,
     tie_embeddings=False,
-    notes="SWA 4096 makes long_500k decode eligible (sub-quadratic).",
 )
 
 
@@ -28,5 +28,4 @@ def reduced() -> ArchConfig:
     import dataclasses
     return dataclasses.replace(
         CONFIG, n_layers=2, d_model=64, n_heads=4, n_kv_heads=2, head_dim=16,
-        d_ff=96, moe_d_ff=96, vocab_size=256, n_experts=4, top_k=2,
-        sliding_window=16)
+        d_ff=96, moe_d_ff=96, vocab_size=256, n_experts=4, top_k=2)

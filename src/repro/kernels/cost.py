@@ -213,6 +213,33 @@ def matmul_cost(cfg: Mapping, M: int, K: int, N: int):
     return _finish(t, hbm, vmem, flops)
 
 
+def grouped_matmul_cost(cfg: Mapping, m: int, k: int, n: int, g: int):
+    """The expert layer's grouped matmul, ``m`` rows spread over ``g``
+    groups (every group non-empty where ``m >= g``). Pallas impl: the grid
+    visits each (m-tile, group) pair that holds rows, at most
+    ``m_tiles + groups - 1``; each visit streams that group's (k, n)
+    weights through VMEM once and its lhs tile once per n-tile. XLA impl
+    (``ragged_dot``): modeled as every group's weights read once."""
+    from repro.kernels.grouped_matmul import grouped_tiles
+
+    impl = str(cfg.get("impl", "pallas"))
+    touched = min(g, m)
+    if impl == "xla":
+        hbm = (g * k * n + m * k + m * n) * _BF16
+        flops = 2.0 * m * k * n
+        t = max(flops / HW.peak_flops, hbm / HW.hbm_bw)
+        return _finish(t, hbm, 0.0, flops)
+    tm, tk, tn, mp = grouped_tiles(m, k, n, int(cfg.get("tm", 128)),
+                                   int(cfg.get("tk", 512)), int(cfg.get("tn", 1024)))
+    visits = mp // tm + touched - 1
+    hbm = (visits * k * n + visits * tm * k * (n // tn) + mp * n) * _BF16
+    # double-buffered lhs/rhs/out blocks, and the f32 accumulator
+    vmem = 2 * (tm * tk + tk * tn + tm * tn) * _BF16 + tm * tn * _F32
+    flops = 2.0 * visits * tm * k * n
+    t = max(flops / (HW.peak_flops * _align_eff(tm, tn, tk)), hbm / HW.hbm_bw)
+    return _finish(t, hbm, vmem, flops)
+
+
 KERNEL_COST_FNS = {
     "syr2k": syr2k_cost,
     "mm3": mm3_cost,
@@ -223,6 +250,7 @@ KERNEL_COST_FNS = {
     "flash_attention": flash_attention_cost,
     "decode_attention": decode_attention_cost,
     "matmul": matmul_cost,
+    "grouped_matmul": grouped_matmul_cost,
 }
 
 

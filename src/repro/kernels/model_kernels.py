@@ -29,6 +29,7 @@ import jax.numpy as jnp
 
 from repro.kernels.decode_attention import chunked_decode_xla, decode_attention
 from repro.kernels.flash_attention import flash_attention
+from repro.kernels.grouped_matmul import grouped_matmul, grouped_matmul_xla
 from repro.kernels.variants import blocked_matmul_host
 
 __all__ = [
@@ -36,7 +37,8 @@ __all__ = [
     "decode_attention_builder", "decode_attention_signature",
     "flash_attention_signature", "init_flash_attention", "init_matmul",
     "init_decode_attention", "decode_attention_host",
-    "flash_attention_host", "matmul_host", "MODEL_KERNEL_BUILDERS",
+    "flash_attention_host", "matmul_host", "grouped_matmul_builder",
+    "init_grouped_matmul", "grouped_matmul_host", "MODEL_KERNEL_BUILDERS",
     "register_model_kernels",
 ]
 
@@ -121,10 +123,23 @@ def matmul_builder(cfg: Mapping[str, Any]):
         pack=bool(cfg.get("pack", False)))
 
 
+def grouped_matmul_builder(cfg: Mapping[str, Any]):
+    """The expert layer's grouped matmul: the Pallas kernel with its
+    ``tm``/``tk``/``tn`` tiles, or XLA's ``ragged_dot``."""
+    impl = str(cfg.get("impl", "pallas"))
+    tiles = {t: int(cfg.get(t, d)) for t, d in (("tm", 128), ("tk", 512), ("tn", 1024))}
+    if impl == "xla":
+        return functools.partial(grouped_matmul_xla, **tiles)
+    if impl == "pallas":
+        return functools.partial(grouped_matmul, **tiles)
+    raise ValueError(f"unknown grouped_matmul impl {impl!r}")
+
+
 MODEL_KERNEL_BUILDERS = {
     "flash_attention": flash_attention_builder,
     "decode_attention": decode_attention_builder,
     "matmul": matmul_builder,
+    "grouped_matmul": grouped_matmul_builder,
 }
 
 
@@ -192,6 +207,20 @@ def init_matmul(M: int, K: int, N: int, dtype=jnp.float32, seed: int = 0):
     return a, b
 
 
+def init_grouped_matmul(m: int, k: int, n: int, g: int, dtype=jnp.float32,
+                        seed: int = 0):
+    """``(lhs, rhs, group_sizes)``: ``m`` rows over ``g`` groups of uneven
+    sizes, the second group empty (a decode step that routes no token to an
+    expert), as the expert layer hands the kernel."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 2)
+    lhs = jax.random.normal(ks[0], (m, k), dtype)
+    rhs = jax.random.normal(ks[1], (g, k, n), dtype) / jnp.sqrt(k).astype(dtype)
+    weights = [0 if i == 1 else i + 1 for i in range(g)]
+    sizes = [m * w // sum(weights) for w in weights]
+    sizes[-1] += m - sum(sizes)
+    return lhs, rhs, jnp.asarray(sizes, jnp.int32)
+
+
 def flash_attention_host(problem):
     def factory(cfg):
         return flash_attention_builder(cfg), problem
@@ -209,5 +238,12 @@ def decode_attention_host(problem):
 def matmul_host(problem):
     def factory(cfg):
         return matmul_builder(cfg), problem
+
+    return factory
+
+
+def grouped_matmul_host(problem):
+    def factory(cfg):
+        return grouped_matmul_builder(cfg), problem
 
     return factory

@@ -38,6 +38,7 @@ __all__ = [
     "BENCH_PROBLEMS",
     "DEFAULTS_TPU",
     "LARGE_SHAPES",
+    "MIXTRAL_GMM_SHAPES",
     "PROXY_DIMS",
     "bench_problem",
     "campaign_dims",
@@ -62,6 +63,7 @@ BENCH_DIMS = {
     "flash_attention": (4, 128, 128, 64),
     "decode_attention": (8, 2, 128, 64),   # (BH, G, seq_bucket, hd)
     "matmul": (256, 192, 224),
+    "grouped_matmul": (64, 256, 384, 8),   # (rows, k, n, groups)
 }
 
 # the paper's LARGE dataset sizes per kernel; the model kernels (serving hot
@@ -76,6 +78,17 @@ LARGE_SHAPES = {
     "flash_attention": (16, 4096, 4096, 128),
     "decode_attention": (16, 8, 4096, 128),
     "matmul": (2000, 2300, 2600),
+    "grouped_matmul": (64, 4096, 14336, 8),
+}
+
+# the expert layer's gate/up grouped matmul in the mixtral-8x7b serving cell
+# (bench/configs/mixtral-8x7b.json): a decode step of 32 sequences routes
+# 64 rows over the 8 experts; a 128-token prompt's forward routes 256. The
+# kernel takes the 4 layers' stacks whole, as 32 groups of which one
+# layer's 8 hold rows (models/moe.py).
+MIXTRAL_GMM_SHAPES = {
+    "decode": (64, 4096, 14336, 32),
+    "prefill": (256, 4096, 14336, 32),
 }
 
 # reduced-shape proxy sizes (repro.fidelity rung 1): the same kernels at
@@ -93,6 +106,7 @@ PROXY_DIMS = {
     "flash_attention": (2, 64, 64, 64),
     "decode_attention": (4, 2, 64, 64),
     "matmul": (128, 96, 112),
+    "grouped_matmul": (32, 128, 256, 8),
 }
 
 DEFAULTS_TPU = {
@@ -105,6 +119,7 @@ DEFAULTS_TPU = {
     "flash_attention": dict(impl="pallas", bq=128, bk=128),
     "decode_attention": dict(impl="pallas", bk=128, hg=1, page=128),
     "matmul": dict(bm=128, bn=128, bk=128, pack=True),
+    "grouped_matmul": dict(impl="pallas", tm=128, tk=1024, tn=2048),
 }
 
 
@@ -126,6 +141,8 @@ def bench_problem(name: str, dims: tuple | None = None):
         return MK.decode_attention_host(MK.init_decode_attention(*dims))
     if name == "matmul":
         return MK.matmul_host(MK.init_matmul(*dims))
+    if name == "grouped_matmul":
+        return MK.grouped_matmul_host(MK.init_grouped_matmul(*dims))
     init = getattr(R, f"init_{name}")
     host = getattr(V, f"{name}_host")
     return host(init(*dims))
@@ -143,6 +160,8 @@ def tpu_problem(name: str, dims: tuple | None = None):
     The op runs in interpret mode off a TPU, which only tests want."""
     import functools
 
+    import jax.numpy as jnp
+
     from repro.kernels import model_kernels as MK
     from repro.kernels import ops
     from repro.kernels import ref as R
@@ -152,6 +171,8 @@ def tpu_problem(name: str, dims: tuple | None = None):
         return MK.flash_attention_host(MK.init_flash_attention(*dims))
     if name == "decode_attention":
         return MK.decode_attention_host(MK.init_decode_attention(*dims))
+    if name == "grouped_matmul":
+        return MK.grouped_matmul_host(MK.init_grouped_matmul(*dims, dtype=jnp.bfloat16))
     if name == "matmul":
         op, args = ops.matmul_op, MK.init_matmul(*dims)
     elif name == "heat3d":
@@ -219,6 +240,9 @@ def dims_from_signature(kernel: str, signature) -> tuple:
     if kernel == "matmul":
         (M, K), (_, N) = signature[0], signature[1]
         return (M, K, N)
+    if kernel == "grouped_matmul":
+        (m, k), (g, _, n) = signature[0], signature[1]
+        return (m, k, n, g)
     raise KeyError(f"unknown kernel {kernel!r}")
 
 

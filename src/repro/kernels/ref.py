@@ -195,4 +195,7 @@ def problem_signature(name: str, *dims: int) -> tuple:
     if name == "matmul":
         M, K, N = dims
         return ((M, K), (K, N))
+    if name == "grouped_matmul":
+        m, k, n, g = dims
+        return ((m, k), (g, k, n), (g,))
     raise KeyError(f"unknown kernel {name!r}")

@@ -183,6 +183,32 @@ def matmul_space(target: str = "tpu", seed: int = 1234) -> ConfigurationSpace:
     return cs
 
 
+# grouped-matmul tiles: rows, contraction and output columns
+GMM_ROWS_TPU = (16, 32, 64, 128, 256, 512)
+GMM_TILES_TPU = (128, 256, 512, 1024, 2048)
+GMM_ROWS_HOST = (16, 32, 64, 128)
+GMM_TILES_HOST = (32, 64, 128, 256)
+
+
+def grouped_matmul_space(target: str = "tpu", seed: int = 1234) -> ConfigurationSpace:
+    """The expert layer's grouped matmul: row tile ``tm``, contraction tile
+    ``tk``, output tile ``tn``, and the ``impl`` axis (Pallas kernel vs
+    XLA's ``ragged_dot``). The TPU default streams 4 MiB weight blocks,
+    which Mosaic accepts at the Mixtral-8x7B shapes
+    (``tests/test_tpu_compile.py``)."""
+    cs = ConfigurationSpace(seed=seed)
+    rows = GMM_ROWS_TPU if target == "tpu" else GMM_ROWS_HOST
+    tiles = GMM_TILES_TPU if target == "tpu" else GMM_TILES_HOST
+    cs.add_hyperparameters([
+        Categorical("impl", ("pallas", "xla"),
+                    default="pallas" if target == "tpu" else "xla"),
+        Ordinal("tm", rows, default=128),
+        Ordinal("tk", tiles, default=1024 if target == "tpu" else 128),
+        Ordinal("tn", tiles, default=2048 if target == "tpu" else 128),
+    ])
+    return cs
+
+
 KERNEL_SPACES = {
     "syr2k": syr2k_space,
     "mm3": mm3_space,
@@ -193,6 +219,7 @@ KERNEL_SPACES = {
     "flash_attention": flash_attention_space,
     "decode_attention": decode_attention_space,
     "matmul": matmul_space,
+    "grouped_matmul": grouped_matmul_space,
 }
 
 

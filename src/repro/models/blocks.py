@@ -36,7 +36,7 @@ __all__ = [
 
 
 # ---------------------------------------------------------------------------
-# per-layer attention-window schedule (mixtral SWA, gemma3 local:global)
+# per-layer attention-window schedule (uniform windows, gemma3 local:global)
 # ---------------------------------------------------------------------------
 
 
@@ -97,8 +97,8 @@ def _qkv(p, h, cfg, positions):
     k = k.reshape(B, S, cfg.n_kv_heads, hd).astype(cfg.dtype)
     v = v.reshape(B, S, cfg.n_kv_heads, hd).astype(cfg.dtype)
     if "q_norm" in p:
-        q = rms_norm(q, p["q_norm"])
-        k = rms_norm(k, p["k_norm"])
+        q = rms_norm(q, p["q_norm"], cfg.rms_eps)
+        k = rms_norm(k, p["k_norm"], cfg.rms_eps)
     if positions is not None:
         if cfg.mrope:
             q = mrope(q, positions, cfg.rope_theta)
@@ -111,14 +111,15 @@ def _qkv(p, h, cfg, positions):
 
 def attn_layer_train(p, x, *, cfg: ArchConfig, positions, window=None,
                      moe: bool = False, causal: bool = True, chunk: int = 512,
-                     service=None):
+                     service=None, layer=None):
     """Returns (x, aux). positions: (B,S) or (B,3,S) for M-RoPE; window: traced
     scalar (0 = full attention). ``service`` routes attention and the output
-    projection through :mod:`repro.dispatch` tuned variants."""
+    projection through :mod:`repro.dispatch` tuned variants. ``layer``: the
+    layer's index into the expert stacks in ``p["moe"]`` (``moe_ffn``)."""
     # window is a traced per-layer scalar inside the stage scan, so the flash
     # route is gated statically: only archs with no windowed layers qualify
     svc_attn = service if not (cfg.sliding_window or cfg.local_global_ratio) else None
-    h = rms_norm(x, p["ln1"])
+    h = rms_norm(x, p["ln1"], cfg.rms_eps)
     if cfg.attn_type == "mla":
         attn = mla_attention(p["mla"], h, cfg,
                              positions if positions.ndim == 2 else positions[:, 0])
@@ -130,11 +131,10 @@ def attn_layer_train(p, x, *, cfg: ArchConfig, positions, window=None,
         B, S = x.shape[:2]
         x = x + service_matmul(o.reshape(B, S, -1), p["wo"], service)
 
-    h2 = rms_norm(x, p["ln2"])
+    h2 = rms_norm(x, p["ln2"], cfg.rms_eps)
     if moe:
-        y, aux = moe_ffn(p["moe"], h2, top_k=cfg.top_k,
-                         capacity_factor=cfg.capacity_factor,
-                         group_size=cfg.moe_group)
+        y, aux = moe_ffn(p["moe"], h2, top_k=cfg.top_k, service=service,
+                         layer=layer)
     else:
         y, aux = mlp(p["mlp"], h2), jnp.zeros((), jnp.float32)
     return x + y, aux
@@ -142,16 +142,17 @@ def attn_layer_train(p, x, *, cfg: ArchConfig, positions, window=None,
 
 def attn_layer_decode(p, x, cache, pos, *, cfg: ArchConfig, window=None,
                       moe: bool = False, mla_absorb: bool = True,
-                      service=None):
+                      service=None, layer=None):
     """x: (B,1,d); cache: {'k': (B,S,K,hd), 'v': ...} or MLA latent cache.
     Returns (x, cache, aux). ``pos`` may be a (B,) vector for the GQA
     family (continuous batching: per-sequence decode positions; the cache
     insert becomes a per-row scatter). ``service`` routes the output
     projection through the tuned blocked matmul and — for archs with no
     windowed layers, where the per-layer window scalar is statically zero —
-    single-token attention through the tuned ``decode_attention`` kernel."""
+    single-token attention through the tuned ``decode_attention`` kernel.
+    ``layer`` as in :func:`attn_layer_train`."""
     B = x.shape[0]
-    h = rms_norm(x, p["ln1"])
+    h = rms_norm(x, p["ln1"], cfg.rms_eps)
     if cfg.attn_type == "mla":
         attn, cache = mla_decode(p["mla"], h, cache, cfg, pos, absorb=mla_absorb)
         x = x + attn
@@ -188,11 +189,10 @@ def attn_layer_decode(p, x, cache, pos, *, cfg: ArchConfig, window=None,
                        ring=ring, service=svc_attn)
         x = x + service_matmul(o.reshape(B, 1, -1), p["wo"], service)
 
-    h2 = rms_norm(x, p["ln2"])
+    h2 = rms_norm(x, p["ln2"], cfg.rms_eps)
     if moe:
-        y, aux = moe_ffn(p["moe"], h2, top_k=cfg.top_k,
-                         capacity_factor=cfg.capacity_factor,
-                         group_size=cfg.moe_group)
+        y, aux = moe_ffn(p["moe"], h2, top_k=cfg.top_k, service=service,
+                         layer=layer)
     else:
         y, aux = mlp(p["mlp"], h2), jnp.zeros((), jnp.float32)
     return x + y, cache, aux
@@ -214,11 +214,13 @@ def init_mamba_layer(key, cfg: ArchConfig) -> dict:
 
 
 def mamba_layer_train(p, x, *, cfg: ArchConfig, chunk: int = 64):
-    return x + mamba2_forward(p["mixer"], rms_norm(x, p["ln"]), cfg, chunk=chunk)
+    h = rms_norm(x, p["ln"], cfg.rms_eps)
+    return x + mamba2_forward(p["mixer"], h, cfg, chunk=chunk)
 
 
 def mamba_layer_decode(p, x, cache, *, cfg: ArchConfig):
-    y, cache = mamba2_decode(p["mixer"], rms_norm(x, p["ln"]), cache, cfg)
+    h = rms_norm(x, p["ln"], cfg.rms_eps)
+    y, cache = mamba2_decode(p["mixer"], h, cache, cfg)
     return x + y, cache
 
 
@@ -244,7 +246,7 @@ def init_cross_layer(key, cfg: ArchConfig) -> dict:
 def _cross_attend(p, x, enc_k, enc_v, cfg):
     B, S, _ = x.shape
     hd = cfg.hd
-    h = rms_norm(x, p["ln_x"])
+    h = rms_norm(x, p["ln_x"], cfg.rms_eps)
     q = (h @ p["xq"]).reshape(B, S, cfg.n_heads, hd).astype(cfg.dtype)
     o = gqa_attention(q, enc_k, enc_v, causal=False, window=None)
     return x + o.reshape(B, S, -1) @ p["xo"]
@@ -252,19 +254,19 @@ def _cross_attend(p, x, enc_k, enc_v, cfg):
 
 def cross_layer_train(p, x, enc_kv, *, cfg: ArchConfig, positions):
     """Decoder layer: causal self-attn, cross-attn to encoder K/V, MLP."""
-    h = rms_norm(x, p["ln1"])
+    h = rms_norm(x, p["ln1"], cfg.rms_eps)
     q, k, v = _qkv(p, h, cfg, positions)
     B, S = x.shape[:2]
     o = gqa_attention(q, k, v, causal=True, window=None)
     x = x + o.reshape(B, S, -1) @ p["wo"]
     x = _cross_attend(p, x, enc_kv["k"], enc_kv["v"], cfg)
-    y = mlp(p["mlp"], rms_norm(x, p["ln2"]))
+    y = mlp(p["mlp"], rms_norm(x, p["ln2"], cfg.rms_eps))
     return x + y
 
 
 def cross_layer_decode(p, x, cache, enc_kv, pos, *, cfg: ArchConfig):
     B = x.shape[0]
-    h = rms_norm(x, p["ln1"])
+    h = rms_norm(x, p["ln1"], cfg.rms_eps)
     positions = jnp.full((B, 1), pos)
     q, k, v = _qkv(p, h, cfg, positions)
     cache = {
@@ -276,5 +278,5 @@ def cross_layer_decode(p, x, cache, enc_kv, pos, *, cfg: ArchConfig):
     o = gqa_decode(q, cache["k"], cache["v"], pos)
     x = x + o.reshape(B, 1, -1) @ p["wo"]
     x = _cross_attend(p, x, enc_kv["k"], enc_kv["v"], cfg)
-    y = mlp(p["mlp"], rms_norm(x, p["ln2"]))
+    y = mlp(p["mlp"], rms_norm(x, p["ln2"], cfg.rms_eps))
     return x + y, cache

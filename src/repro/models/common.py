@@ -56,9 +56,9 @@ class ArchConfig:
     qk_norm: bool = False
     attn_f32: bool = True            # attention scores/softmax in f32 (knob)
 
+    rms_eps: float = 1.0e-6          # epsilon of the layers' and final RMSNorm
+
     # MoE
-    capacity_factor: float = 1.25
-    moe_group: int = 2048            # GShard dispatch group size (tunable)
     n_experts: int = 0
     n_shared_experts: int = 0
     top_k: int = 0

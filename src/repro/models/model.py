@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models import blocks as B
+from repro.models import moe as MOE
 from repro.models.common import ArchConfig, dense_init, rms_norm, service_matmul
 from repro.models.mla import init_mla_cache
 from repro.models.ssm import init_ssm_cache
@@ -121,20 +122,36 @@ def _maybe_remat(fn, remat: str):
     raise ValueError(f"unknown remat policy {remat!r}")
 
 
+def _experts_apart(params_stack, moe: bool):
+    """The scanned layer parameters without the expert matrices, the layer
+    indices to scan beside them (None without experts), and a function that
+    puts the matrices, as whole stacks, back into a layer's slice:
+    ``moe_ffn`` indexes them by layer, as a slice handed to its Pallas call
+    would be copied out on every call."""
+    if not moe:
+        return params_stack, None, lambda p_l: p_l
+    mats = {k: params_stack["moe"][k] for k in MOE.EXPERT_MATS}
+    rest = {k: v for k, v in params_stack["moe"].items() if k not in mats}
+    return ({**params_stack, "moe": rest}, jnp.arange(mats["wg"].shape[0]),
+            lambda p_l: {**p_l, "moe": {**p_l["moe"], **mats}})
+
+
 def _scan_attn_stage(params_stack, x, windows, *, cfg, positions, moe, remat,
                      chunk, act_spec=None, service=None):
+    params_stack, layers, whole = _experts_apart(params_stack, moe)
+
     def body(carry, xs):
         x, aux = carry
-        p_l, w_l = xs
-        x, a = B.attn_layer_train(p_l, x, cfg=cfg, positions=positions,
+        p_l, w_l, i = xs
+        x, a = B.attn_layer_train(whole(p_l), x, cfg=cfg, positions=positions,
                                   window=w_l, moe=moe, chunk=chunk,
-                                  service=service)
+                                  service=service, layer=i)
         x = _cst(x, act_spec)
         return (x, aux + a), None
 
     body = _maybe_remat(body, remat)
     (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                               (params_stack, windows))
+                               (params_stack, windows, layers))
     return x, aux
 
 
@@ -227,7 +244,7 @@ def forward(params: dict, batch: dict, cfg: ArchConfig, *, remat: str = "none",
     else:
         raise ValueError(fam)
 
-    x = rms_norm(x, params["final_norm"])
+    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     if cfg.tie_embeddings:
         logits = service_matmul(x, params["embed"].T.astype(cfg.dtype), service)
     else:
@@ -255,8 +272,8 @@ def loss_fn(params: dict, batch: dict, cfg: ArchConfig, **fw_kw):
 def init_cache(cfg: ArchConfig, batch: int, max_len: int, dtype=None) -> dict:
     """Stacked per-layer cache pytree (leading L axis per stage).
 
-    Uniform-sliding-window archs (mixtral) get a ring buffer of window size
-    instead of max_len — O(window) cache for the 500k decode cell."""
+    Uniform-sliding-window archs get a ring buffer of window size instead
+    of max_len — O(window) cache for the 500k decode cell."""
     dtype = dtype or cfg.dtype
     hd = cfg.hd
     alloc = max_len
@@ -332,15 +349,17 @@ def decode_step(params: dict, cache: dict, token: jnp.ndarray, pos,
                                            mla_absorb=mla_absorb, service=service)
             new_cache["dense0"] = c0
 
+        layer_params, layers, whole = _experts_apart(params["layers"], moe)
+
         def body(x, xs):
-            p_l, c_l, w_l = xs
-            x, c_l, _ = B.attn_layer_decode(p_l, x, c_l, pos, cfg=cfg,
+            p_l, c_l, w_l, i = xs
+            x, c_l, _ = B.attn_layer_decode(whole(p_l), x, c_l, pos, cfg=cfg,
                                             window=w_l, moe=moe,
                                             mla_absorb=mla_absorb,
-                                            service=service)
+                                            service=service, layer=i)
             return x, c_l
 
-        x, cs = jax.lax.scan(body, x, (params["layers"], cache["layers"], windows))
+        x, cs = jax.lax.scan(body, x, (layer_params, cache["layers"], windows, layers))
         new_cache["layers"] = cs
     elif fam == "ssm":
         def body(x, xs):
@@ -393,7 +412,7 @@ def decode_step(params: dict, cache: dict, token: jnp.ndarray, pos,
     else:
         raise ValueError(fam)
 
-    x = rms_norm(x, params["final_norm"])
+    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     if cfg.tie_embeddings:
         logits = service_matmul(x, params["embed"].T.astype(cfg.dtype), service)
     else:

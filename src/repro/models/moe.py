@@ -1,10 +1,22 @@
-"""Mixture-of-experts FFN: top-k routing with GShard-style capacity dispatch.
+"""Mixture-of-experts FFN: dropless top-k routing over a grouped matmul.
 
-The dispatch/combine tensors are (T, E, C); sharding E over the expert axes
-makes the per-device slice small and lets XLA SPMD lower the token exchange
-to all-to-all / all-gather — the collective pattern the roofline's
-collective term measures. Shared experts (DeepSeek-V2) are an always-on
-dense MLP fused alongside the routed path.
+The layer routes every token over all ``E`` experts the router scores,
+sorts the (token, expert) pairs by expert and runs the experts it holds as
+three grouped matmuls (gate, up, down) over the sorted rows. No token is
+dropped, whatever the routing's skew. The layer holds a contiguous range
+``[first, first + n_held)`` of the ``E`` experts, read from its weights'
+leading axis: pairs routed elsewhere contribute nothing here, as on a chip
+of an expert-parallel deployment that holds this share. Shared experts
+(DeepSeek-V2) are an always-on dense MLP added alongside the routed path.
+
+Inside a scan over stacked layers the expert matrices are not sliced per
+layer: a slice handed to a Pallas call is copied out first, on every call
+(a Mixtral layer's three are 2.8 GB), so ``moe_ffn`` takes the whole stack
+and the layer's index (``layer``), and the grouped-matmul kernel reads the
+layer's experts in place.
+
+Named scopes ``moe.route``, ``moe.experts`` and ``moe.combine`` label the
+layer's parts in a profile's op metadata.
 """
 
 from __future__ import annotations
@@ -14,7 +26,7 @@ import jax.numpy as jnp
 
 from repro.models.common import dense_init
 
-__all__ = ["init_moe", "moe_ffn", "init_mlp", "mlp"]
+__all__ = ["init_moe", "moe_ffn", "init_mlp", "mlp", "EXPERT_MATS"]
 
 
 # ---------------------------------------------------------------------------
@@ -54,64 +66,77 @@ def init_moe(key, d: int, eff: int, n_experts: int, n_shared: int, dtype) -> dic
     return p
 
 
+# the routed experts' matrices, leading axis the experts held
+EXPERT_MATS = ("wg", "wu", "wd")
+
+
+def _grouped(service, lhs, rhs, sizes, layer):
+    """One grouped matmul over the experts of ``rhs`` ``(E, k, n)``, or of
+    its layer ``layer`` where ``rhs`` is a stack ``(L, E, k, n)``: the
+    dispatched kernel with a service, else XLA's ``ragged_dot``. The kernel
+    takes a stack whole, as ``L * E`` groups of which only this layer's
+    hold rows, so that no layer's matrices are copied out of it."""
+    if service is None:
+        if layer is not None:
+            rhs = jax.lax.dynamic_index_in_dim(rhs, layer, keepdims=False)
+        return jax.lax.ragged_dot(lhs, rhs, sizes)
+    if layer is not None:
+        L, E = rhs.shape[:2]
+        rhs = rhs.reshape(L * E, *rhs.shape[2:])
+        sizes = jax.lax.dynamic_update_slice(jnp.zeros(L * E, sizes.dtype), sizes,
+                                             (layer * E,))
+    return service.dispatch("grouped_matmul", lhs, rhs, sizes)(lhs, rhs, sizes)
+
+
 def moe_ffn(
     p: dict,
     x: jnp.ndarray,             # (B, S, d)
     *,
     top_k: int,
-    capacity_factor: float = 1.25,
-    group_size: int = 2048,
+    first: int = 0,
+    service=None,
+    layer=None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Returns (y, aux_loss). Capacity-dropped tokens fall through to the
-    residual stream (their routed contribution is zero), GShard semantics.
-
-    Dispatch is *grouped*: tokens are split into groups of ``group_size`` and
-    capacity is per group (C = cf * G * k / E), so the dense dispatch einsum
-    costs cf*k*G per token instead of cf*k*T — without grouping the GShard
-    formulation is quadratic in sequence length (measured: a 230x FLOP blowup
-    on the 32k-prefill dry-run cells). ``group_size`` is an autotuner knob."""
+    """Returns (y, aux_loss). Router logits in float32 over all ``E``
+    experts, a softmax, the top ``top_k`` renormalised over themselves.
+    The experts held are ``p["wg"]``'s leading axis, ``first`` onwards;
+    ``service`` (a :class:`repro.dispatch.DispatchService`) routes the
+    grouped matmuls through the tuned ``grouped_matmul`` kernel. With
+    ``layer`` (a traced index), the expert matrices are the stacks of every
+    layer and this is layer ``layer``."""
     B, S, d = x.shape
     E = p["router"].shape[1]
+    n_held = p["wg"].shape[-3]
     T = B * S
     xt = x.reshape(T, d)
 
-    G = min(group_size, T)
-    pad = (-T) % G
-    if pad:
-        xt = jnp.pad(xt, ((0, pad), (0, 0)))
-    ng = xt.shape[0] // G
-    xg = xt.reshape(ng, G, d)
+    with jax.named_scope("moe.route"):
+        probs = jax.nn.softmax(xt.astype(jnp.float32) @ p["router"], axis=-1)
+        gate, idx = jax.lax.top_k(probs, top_k)                      # (T, k)
+        gate = gate / gate.sum(-1, keepdims=True)
+        # experts counted from the first held one: held pairs sort first, in
+        # expert order, and the rows past them belong to other chips' experts
+        local = (idx - first) % E
+        order = jnp.argsort(local.reshape(-1), stable=True)         # (T*k,)
+        sizes = jnp.sum(jax.nn.one_hot(local, n_held, dtype=jnp.int32),
+                        axis=(0, 1))                                 # (n_held,)
+        xs = xt[order // top_k]                                      # (T*k, d)
 
-    logits = xg.astype(jnp.float32) @ p["router"]          # (ng, G, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, gate_idx = jax.lax.top_k(probs, top_k)      # (ng, G, k)
-    gate_vals = gate_vals / (gate_vals.sum(-1, keepdims=True) + 1e-9)
+    with jax.named_scope("moe.experts"):
+        h = jax.nn.silu(_grouped(service, xs, p["wg"], sizes, layer)) \
+            * _grouped(service, xs, p["wu"], sizes, layer)
+        ys = _grouped(service, h, p["wd"], sizes, layer)             # (T*k, d)
 
-    onehot = jax.nn.one_hot(gate_idx, E, dtype=jnp.float32)        # (ng, G, k, E)
-    gates = (onehot * gate_vals[..., None]).sum(axis=2)            # (ng, G, E)
-    mask = onehot.sum(axis=2)                                      # (ng, G, E)
+    with jax.named_scope("moe.combine"):
+        # back to (token, choice) order, weighted by the gates; a pair that
+        # went to an expert not held here adds nothing
+        yk = ys[jnp.argsort(order)].reshape(T, top_k, d).astype(jnp.float32)
+        w = jnp.where(local < n_held, gate, 0.0)
+        y = jnp.einsum("tk,tkd->td", w, yk).astype(x.dtype).reshape(B, S, d)
 
-    # GShard load-balancing aux loss (over real tokens only)
-    me = probs.reshape(-1, E)[:T].mean(axis=0)
-    ce = mask.reshape(-1, E)[:T].mean(axis=0) / max(top_k, 1)
-    aux = E * jnp.sum(me * ce)
-
-    # per-group capacity assignment
-    C = max(int(capacity_factor * G * top_k / E), 4)
-    pos = jnp.cumsum(mask, axis=1) - 1.0                           # (ng, G, E)
-    keep = mask * (pos < C)
-    pos = jnp.where(keep > 0, pos, 0).astype(jnp.int32)
-
-    disp = keep[..., None] * jax.nn.one_hot(pos, C, dtype=jnp.float32)  # (ng,G,E,C)
-    comb = disp * gates[..., None]
-
-    cd = x.dtype
-    xe = jnp.einsum("gtec,gtd->gecd", disp.astype(cd), xg)         # (ng, E, C, d)
-    h = jax.nn.silu(jnp.einsum("gecd,edf->gecf", xe, p["wg"]))
-    h = h * jnp.einsum("gecd,edf->gecf", xe, p["wu"])
-    ye = jnp.einsum("gecf,efd->gecd", h, p["wd"])                  # (ng, E, C, d)
-    yt = jnp.einsum("gtec,gecd->gtd", comb.astype(cd), ye).reshape(ng * G, d)
-    y = yt[:T].reshape(B, S, d)
+    # load-balancing aux loss (Switch/GShard): routed fraction x mean prob
+    frac = jnp.mean(jax.nn.one_hot(idx, E, dtype=jnp.float32), axis=(0, 1))
+    aux = E * jnp.sum(jnp.mean(probs, axis=0) * frac)
 
     if "shared" in p:
         y = y + mlp(p["shared"], x)

@@ -77,6 +77,11 @@ def test_accepted_sampled_configs_build(kernel):
     # always a whole multiple of the record's page — 48 never divides 128
     ("decode_attention", {"impl": "xla", "bk": 128, "hg": 1, "page": 48},
      BENCH_DIMS["decode_attention"], "host", "page_indivisible:page"),
+    # Mosaic's block rule: a 64-wide lane block of a 4096-wide dim
+    ("grouped_matmul", {"impl": "pallas", "tm": 128, "tk": 64, "tn": 2048},
+     LARGE_SHAPES["grouped_matmul"], "tpu", "lane_misaligned:tk"),
+    ("grouped_matmul", {"impl": "pallas", "tm": 128, "tk": 2048, "tn": 2048},
+     LARGE_SHAPES["grouped_matmul"], "tpu", "vmem_overflow"),
 ])
 def test_known_bad_configs_rejected_with_stable_codes(
         kernel, cfg, dims, target, code):

@@ -5,7 +5,8 @@ and layouts that Mosaic, the TPU's kernel compiler, refuses. These tests
 compile each kernel for a described (not attached) v5e chip at the sizes
 the chip paths run — the paper's six kernels at ``LARGE_SHAPES`` with
 ``DEFAULTS_TPU``, flash and decode attention at the qwen2-0.5b prefill and
-decode shapes, and the blocked matmul — and check that the compiled program
+decode shapes, the blocked matmul, and the grouped matmul at the
+mixtral-8x7b cell's decode and prefill shapes — and check that the compiled program
 holds the kernel (``tpu_custom_call``) rather than a fallback. Nothing runs,
 so they say nothing about results or times.
 
@@ -28,8 +29,9 @@ from repro.configs import get_config
 from repro.kernels import ops
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
+from repro.kernels.grouped_matmul import grouped_matmul
 from repro.kernels.matmul import tiled_matmul
-from repro.kernels.problems import DEFAULTS_TPU, LARGE_SHAPES
+from repro.kernels.problems import DEFAULTS_TPU, LARGE_SHAPES, MIXTRAL_GMM_SHAPES
 from repro.kernels.ref import problem_signature
 
 # the serving shapes chip_smoke.py drives: 4 requests, 128-token prompts,
@@ -42,6 +44,15 @@ _PROMPT, _CACHE = 128, 128 + 32
 
 def _f32(*shapes):
     return [(s, jnp.float32) for s in shapes]
+
+
+def _grouped(phase):
+    """The expert layer's gate/up grouped matmul, bf16, with the space's TPU
+    default tiles."""
+    m, k, n, g = MIXTRAL_GMM_SHAPES[phase]
+    cfg = {t: DEFAULTS_TPU["grouped_matmul"][t] for t in ("tm", "tk", "tn")}
+    return (functools.partial(grouped_matmul, **cfg, interpret=False),
+            [((m, k), jnp.bfloat16), ((g, k, n), jnp.bfloat16), ((g,), jnp.int32)])
 
 
 def _polybench(name):
@@ -70,6 +81,8 @@ CASES = {
     "tiled_matmul": (
         functools.partial(tiled_matmul, **DEFAULTS_TPU["matmul"], interpret=False),
         _f32(*problem_signature("matmul", *LARGE_SHAPES["matmul"]))),
+    "grouped_matmul_decode": _grouped("decode"),
+    "grouped_matmul_prefill": _grouped("prefill"),
     **{name: _polybench(name) for name in
        ("syr2k", "mm3", "lu", "heat3d", "covariance", "floyd_warshall")},
 }
