@@ -111,11 +111,15 @@ def _qkv(p, h, cfg, positions):
 
 def attn_layer_train(p, x, *, cfg: ArchConfig, positions, window=None,
                      moe: bool = False, causal: bool = True, chunk: int = 512,
-                     service=None, layer=None):
+                     service=None, layer=None, emit_kv: bool = False):
     """Returns (x, aux). positions: (B,S) or (B,3,S) for M-RoPE; window: traced
     scalar (0 = full attention). ``service`` routes attention and the output
     projection through :mod:`repro.dispatch` tuned variants. ``layer``: the
-    layer's index into the expert stacks in ``p["moe"]`` (``moe_ffn``)."""
+    layer's index into the expert stacks in ``p["moe"]`` (``moe_ffn``).
+    ``emit_kv`` (GQA only) returns (x, aux, {"k", "v"}) instead: the
+    post-RoPE K/V of every position, ``(B, S, n_kv_heads, hd)`` in
+    ``cfg.dtype``, the tensors attention read — what a prefill writes into
+    the decode cache."""
     # window is a traced per-layer scalar inside the stage scan, so the flash
     # route is gated statically: only archs with no windowed layers qualify
     svc_attn = service if not (cfg.sliding_window or cfg.local_global_ratio) else None
@@ -137,6 +141,8 @@ def attn_layer_train(p, x, *, cfg: ArchConfig, positions, window=None,
                          layer=layer)
     else:
         y, aux = mlp(p["mlp"], h2), jnp.zeros((), jnp.float32)
+    if emit_kv:
+        return x + y, aux, {"k": k, "v": v}
     return x + y, aux
 
 

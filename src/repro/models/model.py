@@ -23,8 +23,8 @@ from repro.models.mla import init_mla_cache
 from repro.models.ssm import init_ssm_cache
 
 __all__ = [
-    "init_params", "abstract_params", "forward", "loss_fn", "init_cache",
-    "decode_step", "make_batch_positions",
+    "init_params", "abstract_params", "forward", "forward_kv", "loss_fn",
+    "init_cache", "cache_len", "decode_step", "make_batch_positions",
 ]
 
 MOE_AUX_COEF = 0.01
@@ -137,22 +137,24 @@ def _experts_apart(params_stack, moe: bool):
 
 
 def _scan_attn_stage(params_stack, x, windows, *, cfg, positions, moe, remat,
-                     chunk, act_spec=None, service=None):
+                     chunk, act_spec=None, service=None, emit_kv=False):
+    """Returns (x, aux, kv): ``kv`` is None, or with ``emit_kv`` every
+    layer's K/V (``attn_layer_train``) stacked on a leading L axis."""
     params_stack, layers, whole = _experts_apart(params_stack, moe)
 
     def body(carry, xs):
         x, aux = carry
         p_l, w_l, i = xs
-        x, a = B.attn_layer_train(whole(p_l), x, cfg=cfg, positions=positions,
-                                  window=w_l, moe=moe, chunk=chunk,
-                                  service=service, layer=i)
+        x, a, *kv = B.attn_layer_train(whole(p_l), x, cfg=cfg, positions=positions,
+                                       window=w_l, moe=moe, chunk=chunk,
+                                       service=service, layer=i, emit_kv=emit_kv)
         x = _cst(x, act_spec)
-        return (x, aux + a), None
+        return (x, aux + a), (kv[0] if emit_kv else None)
 
     body = _maybe_remat(body, remat)
-    (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                               (params_stack, windows, layers))
-    return x, aux
+    (x, aux), kv = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
+                                (params_stack, windows, layers))
+    return x, aux, kv
 
 
 def forward(params: dict, batch: dict, cfg: ArchConfig, *, remat: str = "none",
@@ -161,6 +163,28 @@ def forward(params: dict, batch: dict, cfg: ArchConfig, *, remat: str = "none",
     """Returns (logits, aux_loss). ``service`` (a
     :class:`repro.dispatch.DispatchService`) routes attention and the big
     matmul call sites through tuned, store-resolved kernel variants."""
+    logits, aux, _ = _forward(params, batch, cfg, remat=remat,
+                              attn_chunk=attn_chunk, ssm_chunk=ssm_chunk,
+                              act_spec=act_spec, logits_spec=logits_spec,
+                              service=service)
+    return logits, aux
+
+
+def forward_kv(params: dict, batch: dict, cfg: ArchConfig, **fw_kw):
+    """:func:`forward`'s logits and the post-RoPE K/V of every attention
+    layer, as attention read them (GQA families: dense, vlm, moe). Returns
+    (logits, kv): ``kv`` has :func:`init_cache`'s structure, with the
+    prompt's S positions where the cache has ``cache_len`` — what a prefill
+    writes into its cache in place of replaying the prompt."""
+    logits, _, kv = _forward(params, batch, cfg, emit_kv=True, **fw_kw)
+    return logits, kv
+
+
+def _forward(params: dict, batch: dict, cfg: ArchConfig, *, remat: str = "none",
+             attn_chunk: int = 512, ssm_chunk: int = 64, act_spec=None,
+             logits_spec=None, service=None, emit_kv: bool = False):
+    """:func:`forward`, also returning the K/V that ``emit_kv`` asks of the
+    GQA layers (None without it)."""
     tokens = batch["tokens"]
     Bsz, S = tokens.shape
     x = _cst(params["embed"][tokens].astype(cfg.dtype), act_spec)
@@ -171,28 +195,35 @@ def forward(params: dict, batch: dict, cfg: ArchConfig, *, remat: str = "none",
         positions = make_batch_positions(cfg, tokens)
 
     aux = jnp.zeros((), jnp.float32)
+    kv = None
     fam = cfg.family
 
     if fam in ("dense", "vlm"):
         windows = jnp.asarray(B.layer_windows(cfg))
-        x, aux = _scan_attn_stage(params["layers"], x, windows, cfg=cfg,
-                                  positions=positions, moe=False, remat=remat,
-                                  chunk=attn_chunk, act_spec=act_spec,
-                                  service=service)
+        x, aux, layers_kv = _scan_attn_stage(
+            params["layers"], x, windows, cfg=cfg, positions=positions,
+            moe=False, remat=remat, chunk=attn_chunk, act_spec=act_spec,
+            service=service, emit_kv=emit_kv)
+        if emit_kv:
+            kv = {"layers": layers_kv}
     elif fam == "moe":
+        kv = {} if emit_kv else None
         if "dense0" in params:
-            x, a0 = B.attn_layer_train(params["dense0"], x, cfg=cfg,
-                                       positions=positions, window=None,
-                                       moe=False, chunk=attn_chunk,
-                                       service=service)
+            x, a0, *kv0 = B.attn_layer_train(params["dense0"], x, cfg=cfg,
+                                             positions=positions, window=None,
+                                             moe=False, chunk=attn_chunk,
+                                             service=service, emit_kv=emit_kv)
             aux = aux + a0
-        n_moe = cfg.n_layers - cfg.first_dense_layers
+            if emit_kv:
+                kv["dense0"] = kv0[0]
         windows = jnp.asarray(B.layer_windows(cfg)[cfg.first_dense_layers:])
-        x, a = _scan_attn_stage(params["layers"], x, windows, cfg=cfg,
-                                positions=positions, moe=True, remat=remat,
-                                chunk=attn_chunk, act_spec=act_spec,
-                                service=service)
+        x, a, layers_kv = _scan_attn_stage(
+            params["layers"], x, windows, cfg=cfg, positions=positions,
+            moe=True, remat=remat, chunk=attn_chunk, act_spec=act_spec,
+            service=service, emit_kv=emit_kv)
         aux = aux + a
+        if emit_kv:
+            kv["layers"] = layers_kv
     elif fam in ("ssm", "hybrid"):
         def mamba_body(x, p_l):
             x = B.mamba_layer_train(p_l, x, cfg=cfg, chunk=ssm_chunk)
@@ -250,7 +281,7 @@ def forward(params: dict, batch: dict, cfg: ArchConfig, *, remat: str = "none",
     else:
         logits = service_matmul(x, params["unembed"], service)
     logits = _cst(logits, logits_spec)
-    return logits.astype(jnp.float32), aux
+    return logits.astype(jnp.float32), aux, kv
 
 
 def loss_fn(params: dict, batch: dict, cfg: ArchConfig, **fw_kw):
@@ -276,9 +307,7 @@ def init_cache(cfg: ArchConfig, batch: int, max_len: int, dtype=None) -> dict:
     of max_len — O(window) cache for the 500k decode cell."""
     dtype = dtype or cfg.dtype
     hd = cfg.hd
-    alloc = max_len
-    if cfg.sliding_window and not cfg.local_global_ratio:
-        alloc = min(max_len, cfg.sliding_window)
+    alloc = cache_len(cfg, max_len)
 
     def kv(n):
         return {
@@ -324,6 +353,14 @@ def init_cache(cfg: ArchConfig, batch: int, max_len: int, dtype=None) -> dict:
             },
         }
     raise ValueError(fam)
+
+
+def cache_len(cfg: ArchConfig, max_len: int) -> int:
+    """Positions :func:`init_cache` allocates a sequence: ``max_len``, or a
+    uniform sliding window's ring when that is shorter."""
+    if cfg.sliding_window and not cfg.local_global_ratio:
+        return min(max_len, cfg.sliding_window)
+    return max_len
 
 
 def decode_step(params: dict, cache: dict, token: jnp.ndarray, pos,

@@ -12,7 +12,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.common import ArchConfig
-from repro.models.model import decode_step, forward, init_cache
+from repro.models.model import (
+    cache_len, decode_step, forward, forward_kv, init_cache)
 from repro.obs.metrics import get_registry
 from repro.obs.trace import install_jax_hooks, span
 
@@ -20,14 +21,22 @@ __all__ = ["prefill", "greedy_decode", "make_serve_step"]
 
 
 def prefill(params, batch, cfg: ArchConfig, max_len: int, service=None, **fw_kw):
-    """Run the prompt through the model, then replay it through decode_step to
-    fill the cache (simple, correct reference path; the fused K/V write is
-    ROADMAP Speed 4). ``service`` routes the prompt forward's attention
-    (tuned flash ``bq``/``bk``) and matmul call sites through
-    :mod:`repro.dispatch` — this is where serving traffic finally meets the
-    tuning store.
+    """Run the prompt through the model and fill a ``max_len`` cache with its
+    K/V; returns (logits ``(B, S, V)`` float32, cache). ``service`` routes
+    the prompt forward's attention (tuned flash ``bq``/``bk``) and matmul
+    call sites through :mod:`repro.dispatch` — this is where serving traffic
+    finally meets the tuning store.
 
-    Forward, ``init_cache`` and the replay are one jitted program, traced and
+    GQA attention over a cache of ``max_len`` positions (dense, vlm and moe
+    families, not MLA, not a sliding window's shorter ring) takes the fused
+    path: the forward emits each layer's post-RoPE K/V (``forward_kv``) and
+    one update a cache leaf writes them at positions ``0..S-1``. Every other
+    configuration (MLA's latent cache, SSM state, the hybrid's and the
+    encoder-decoder's caches, a ring) is filled after the forward by
+    replaying the prompt one token at a time through ``decode_step``
+    (``_replay``).
+
+    Forward, ``init_cache`` and the fill are one jitted program, traced and
     compiled once per (config, ``max_len``, ``fw_kw``, batch, prompt length);
     every later call of that shape runs the cached executable. With a
     ``service`` the program lives in the service's executable cache
@@ -40,14 +49,17 @@ def prefill(params, batch, cfg: ArchConfig, max_len: int, service=None, **fw_kw)
 
     Spans (host time; device work is timed to its enqueue): ``serve.prefill``
     (``batch``, ``prompt_len``) around every call. ``serve.prefill.forward``
-    and ``serve.prefill.replay`` sit inside the traced body, so they are
-    recorded only when JAX traces it (a new shape, or after an
-    ``invalidate``); JAX's traces, lowerings and compiles land under
-    ``serve.prefill`` as ``jax.*`` child spans. Counters in the
-    ``repro.obs`` registry: ``serve_prefill_calls_total`` on every call,
-    ``serve_prefill_traces_total`` on every trace."""
+    and then ``serve.prefill.fill`` (fused path) or ``serve.prefill.replay``
+    sit inside the traced body, so they are recorded only when JAX traces it
+    (a new shape, or after an ``invalidate``); JAX's traces, lowerings and
+    compiles land under ``serve.prefill`` as ``jax.*`` child spans. Counters
+    in the ``repro.obs`` registry: ``serve_prefill_calls_total`` on every
+    call, ``serve_prefill_fused_total`` on every call that takes the fused
+    path, ``serve_prefill_traces_total`` on every trace."""
     install_jax_hooks()
     get_registry().add("serve_prefill_calls_total")
+    if _fused(cfg, max_len):
+        get_registry().add("serve_prefill_fused_total")
     if service is not None:
         # the key holds everything the program closes over
         kw = ",".join(f"{k}={v!r}" for k, v in sorted(fw_kw.items()))
@@ -65,24 +77,48 @@ def _prefill_attrs(params, batch) -> dict:
     return {"batch": B, "prompt_len": S}
 
 
+def _fused(cfg: ArchConfig, max_len: int) -> bool:
+    """Whether :func:`prefill` writes the forward's own K/V into the cache:
+    GQA layers whose cache holds every position (not a ring)."""
+    return (cfg.attn_type == "gqa" and cfg.family in ("dense", "vlm", "moe")
+            and cache_len(cfg, max_len) == max_len)
+
+
+def _replay(params, tokens, cfg: ArchConfig, max_len: int, service=None):
+    """A ``max_len`` cache filled by running ``tokens`` ``(B, S)`` one at a
+    time through ``decode_step``: the fill of every configuration off the
+    fused path, and the reference the fused fill is tested against."""
+    B, S = tokens.shape
+
+    def body(cache, t):
+        _, cache = decode_step(params, cache, jax.lax.dynamic_slice_in_dim(
+            tokens, t, 1, axis=1), t, cfg, service=service)
+        return cache, None
+
+    cache, _ = jax.lax.scan(body, init_cache(cfg, B, max_len), jnp.arange(S))
+    return cache
+
+
 def _prefill_program(cfg: ArchConfig, max_len: int, service, fw_kw: dict):
     """The traced body of :func:`prefill`, closed over everything static
     (named ``prefill``: the module's name in a device trace)."""
+    fused = _fused(cfg, max_len)
 
     def prefill(params, batch):
         get_registry().add("serve_prefill_traces_total")
-        B, S = batch["tokens"].shape
+        if not fused:
+            with span("serve.prefill.forward"):
+                logits, _ = forward(params, batch, cfg, service=service, **fw_kw)
+            with span("serve.prefill.replay"):
+                return logits, _replay(params, batch["tokens"], cfg, max_len, service)
         with span("serve.prefill.forward"):
-            logits, _ = forward(params, batch, cfg, service=service, **fw_kw)
-        cache = init_cache(cfg, B, max_len)
-
-        def body(cache, t):
-            _, cache = decode_step(params, cache, jax.lax.dynamic_slice_in_dim(
-                batch["tokens"], t, 1, axis=1), t, cfg, service=service)
-            return cache, None
-
-        with span("serve.prefill.replay"):
-            cache, _ = jax.lax.scan(body, cache, jnp.arange(S))
+            logits, kv = forward_kv(params, batch, cfg, service=service, **fw_kw)
+        with span("serve.prefill.fill"):
+            # the sequence axis is third from the end in every leaf
+            cache = jax.tree_util.tree_map(
+                lambda c, new: jax.lax.dynamic_update_slice_in_dim(
+                    c, new.astype(c.dtype), 0, axis=c.ndim - 3),
+                init_cache(cfg, batch["tokens"].shape[0], max_len), kv)
         return logits, cache
 
     return prefill

@@ -8,8 +8,10 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config, get_reduced
-from repro.models import forward, init_params
+from repro.models import forward, init_params, loss_fn
+from repro.obs.metrics import MetricsRegistry, get_registry, set_registry
 from repro.serve import cache_bytes_per_token, greedy_decode, make_serve_step, prefill
+from repro.serve.step import _replay
 
 KEY = jax.random.PRNGKey(0)
 
@@ -259,33 +261,39 @@ def test_greedy_decode_service_resolves_tuned_decode_record(tmp_path):
     np.testing.assert_array_equal(np.asarray(toks), np.asarray(base))
 
 
-def test_serving_layers_record_their_spans():
+@pytest.mark.parametrize("arch,fill", [("qwen2-0.5b", "serve.prefill.fill"),
+                                       ("mamba2-780m", "serve.prefill.replay")],
+                         ids=["fused", "replay"])
+def test_serving_layers_record_their_spans(arch, fill):
     """prefill, the dispatched serve step and the paged cache record the
     spans the benchmark reads, nested as the calls are: the forward and the
-    replay under ``serve.prefill``, with their attributes. A second prefill
-    of the same shape runs the cached program: one ``serve.prefill`` with
-    nothing below it, and the first call's logits and cache bit for bit."""
+    cache fill (the fused write on a GQA config, the replay elsewhere) under
+    ``serve.prefill``, with their attributes. A second prefill of the same
+    shape runs the cached program: one ``serve.prefill`` with nothing below
+    it, and the first call's logits and cache bit for bit."""
     from repro.dispatch import DispatchService
     from repro.obs import trace
     from repro.serve import PagedKVCache
 
-    cfg = _cfg("qwen2-0.5b")
+    cfg = _cfg(arch)
     params = init_params(cfg, KEY)
     svc = DispatchService()
-    pc = PagedKVCache(cfg, max_batch=2, max_len=16, page_size=8)
-    serve = make_serve_step(cfg, service=svc)
+    paged = fill == "serve.prefill.fill"   # the paged cache takes GQA only
     old = trace.set_span_ring(trace.SpanRing())
     try:
         toks = jax.random.randint(KEY, (1, 5), 0, cfg.vocab_size)
-        logits, cache = prefill(params, {"tokens": toks}, cfg, max_len=pc.alloc,
+        logits, cache = prefill(params, {"tokens": toks}, cfg, max_len=16,
                                 service=svc)
-        logits2, cache2 = prefill(params, {"tokens": toks}, cfg, max_len=pc.alloc,
+        logits2, cache2 = prefill(params, {"tokens": toks}, cfg, max_len=16,
                                   service=svc)
-        pc.admit(1, cache, 5)
-        view = pc.view([0, 1], 8)
-        _, _, view = serve(params, view, jnp.zeros((2, 1), jnp.int32),
-                           jnp.asarray([0, 5], jnp.int32))
-        pc.writeback([0, 1], 8, view)
+        if paged:
+            pc = PagedKVCache(cfg, max_batch=2, max_len=16, page_size=8)
+            serve = make_serve_step(cfg, service=svc)
+            pc.admit(1, cache, 5)
+            view = pc.view([0, 1], 8)
+            _, _, view = serve(params, view, jnp.zeros((2, 1), jnp.int32),
+                               jnp.asarray([0, 5], jnp.int32))
+            pc.writeback([0, 1], 8, view)
         spans = trace.recorded_spans()
     finally:
         trace.set_span_ring(old)
@@ -295,14 +303,18 @@ def test_serving_layers_record_their_spans():
     pre, again = sorted(by["serve.prefill"], key=lambda s: s.start_ns)
     for p in (pre, again):
         assert p.attrs == {"batch": 1, "prompt_len": 5} and p.parent == 0
-    for half in ("serve.prefill.forward", "serve.prefill.replay"):
+    for half in ("serve.prefill.forward", fill):
         (h,) = by[half]
         assert h.parent == pre.id and pre.start_ns <= h.start_ns <= h.end_ns <= pre.end_ns
+    other = {"serve.prefill.fill", "serve.prefill.replay"} - {fill}
+    assert not other & set(by)
     # the same shape again: the cached executable, nothing traced or compiled
     assert not any(s.parent == again.id for s in spans)
     np.testing.assert_array_equal(np.asarray(logits2), np.asarray(logits))
     for a, b in zip(jax.tree_util.tree_leaves(cache2), jax.tree_util.tree_leaves(cache)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    if not paged:
+        return
     assert [s.attrs for s in by["kv.admit"]] == [{"slot": 1}]
     assert [s.attrs for s in by["kv.view"]] == [{"slots": 2, "bucket": 8}]
     assert [s.attrs for s in by["kv.writeback"]] == [{"slots": 2, "bucket": 8}]
@@ -312,10 +324,10 @@ def test_serving_layers_record_their_spans():
     assert any(s.parent == step.id for s in by["jax.compile"])
 
 
-def _prefill_counts(registry) -> tuple[float, float]:
+def _prefill_counts(registry, *names) -> tuple[float, ...]:
     counters = {c["name"]: c["value"] for c in registry.snapshot()["counters"]}
-    return (counters.get("serve_prefill_calls_total", 0.0),
-            counters.get("serve_prefill_traces_total", 0.0))
+    names = names or ("serve_prefill_calls_total", "serve_prefill_traces_total")
+    return tuple(counters.get(n, 0.0) for n in names)
 
 
 @pytest.mark.parametrize("dispatched", [True, False], ids=["service", "no_service"])
@@ -323,7 +335,6 @@ def test_prefill_traces_once_per_prompt_length(dispatched):
     """Calls at three prompt lengths, twice each: six calls, three traces
     (the counters ``/metrics`` shows)."""
     from repro.dispatch import DispatchService
-    from repro.obs.metrics import MetricsRegistry, get_registry, set_registry
 
     cfg = _cfg("qwen2-0.5b")
     params = init_params(cfg, KEY)
@@ -345,7 +356,6 @@ def test_prefill_retraces_once_after_invalidate():
     """A hot swap (``DispatchService.invalidate``) makes the next prefill
     retrace against the new configs, once; later calls reuse that trace."""
     from repro.dispatch import DispatchService
-    from repro.obs.metrics import MetricsRegistry, get_registry, set_registry
 
     cfg = _cfg("qwen2-0.5b")
     params = init_params(cfg, KEY)
@@ -366,3 +376,116 @@ def test_prefill_retraces_once_after_invalidate():
         np.testing.assert_array_equal(np.asarray(again), np.asarray(first))
     finally:
         set_registry(old)
+
+
+# ---------------------------------------------------------------------------
+# the prompt's K/V: written by the forward (GQA) or replayed (the rest)
+# ---------------------------------------------------------------------------
+
+
+def _greedy_from(params, cfg, logits, cache, start, steps=16):
+    """``steps`` greedy tokens decoded from a prefilled cache."""
+    serve = jax.jit(make_serve_step(cfg))
+    tok = jnp.argmax(logits[:, -1, :], -1).astype(jnp.int32)[:, None]
+    out = []
+    for t in range(steps):
+        out.append(np.asarray(tok[:, 0]))
+        tok, _, cache = serve(params, cache, tok, jnp.int32(start + t))
+    return np.stack(out, 1)
+
+
+def _mixtral_dense0():
+    """Mixtral's GQA experts behind one leading dense layer: the structure of
+    DeepSeek's stack without MLA."""
+    return dataclasses.replace(_cfg("mixtral-8x7b"), n_layers=3,
+                               first_dense_layers=1)
+
+
+@pytest.mark.parametrize("arch", ["qwen2-0.5b", "gemma3-1b", "qwen2-vl-7b",
+                                  "mixtral-8x7b", "mixtral-dense0"])
+def test_fused_prefill_matches_the_replay(arch):
+    """On the GQA families the forward's own K/V fill the cache: QKV bias
+    (qwen2), local:global windows over a full-length cache (gemma3, a prompt
+    longer than its window), M-RoPE (qwen2-vl), dropless experts (mixtral,
+    also behind a dense first layer). The cache equals the token-by-token
+    replay's, the logits are the forward's, and 16 greedy steps decode the
+    same tokens from either cache."""
+    cfg = _mixtral_dense0() if arch == "mixtral-dense0" else _cfg(arch)
+    params = init_params(cfg, KEY)
+    B, S, max_len = 2, 12, 32
+    toks = jax.random.randint(jax.random.PRNGKey(7), (B, S), 0, cfg.vocab_size)
+    old = set_registry(MetricsRegistry())
+    try:
+        logits, cache = prefill(params, {"tokens": toks}, cfg, max_len=max_len)
+        assert _prefill_counts(get_registry(), "serve_prefill_calls_total",
+                               "serve_prefill_fused_total") == (1, 1)
+    finally:
+        set_registry(old)
+    ref = jax.jit(lambda p, t: _replay(p, t, cfg, max_len))(params, toks)
+    assert jax.tree_util.tree_structure(cache) == jax.tree_util.tree_structure(ref)
+    for a, b in zip(jax.tree_util.tree_leaves(cache), jax.tree_util.tree_leaves(ref)):
+        assert a.shape == b.shape and a.dtype == b.dtype == cfg.dtype
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=0, atol=1e-5)
+    want, _ = jax.jit(lambda p, t: forward(p, {"tokens": t}, cfg))(params, toks)
+    assert logits.shape == (B, S, cfg.vocab_size) and logits.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(want), rtol=1e-6, atol=1e-6)
+    np.testing.assert_array_equal(_greedy_from(params, cfg, logits, cache, S),
+                                  _greedy_from(params, cfg, logits, ref, S))
+
+
+def _ring():
+    """A uniform sliding window shorter than the cache: a ring buffer."""
+    return dataclasses.replace(_cfg("qwen2-0.5b"), sliding_window=8)
+
+
+@pytest.mark.parametrize("arch", ["deepseek-v2-236b", "mamba2-780m", "zamba2-1.2b",
+                                  "whisper-large-v3", "ring"])
+def test_replay_families_keep_the_replay(arch):
+    """MLA's latent cache, SSM state, the hybrid's shared attention, the
+    encoder-decoder and a ring cache are still filled by the replay, and
+    ``serve_prefill_fused_total`` counts none of their calls."""
+    cfg = _ring() if arch == "ring" else _cfg(arch)
+    params = init_params(cfg, KEY)
+    B, S, max_len = 1, 12, 32
+    toks = jax.random.randint(jax.random.PRNGKey(7), (B, S), 0, cfg.vocab_size)
+    batch = {"tokens": toks}
+    if cfg.family == "audio":
+        batch["enc_embed"] = jax.random.normal(KEY, (B, cfg.encoder_len, cfg.d_model))
+    old = set_registry(MetricsRegistry())
+    try:
+        _, cache = prefill(params, batch, cfg, max_len=max_len)
+        assert _prefill_counts(get_registry(), "serve_prefill_calls_total",
+                               "serve_prefill_fused_total") == (1, 0)
+    finally:
+        set_registry(old)
+    ref = jax.jit(lambda p, t: _replay(p, t, cfg, max_len))(params, toks)
+    for a, b in zip(jax.tree_util.tree_leaves(cache), jax.tree_util.tree_leaves(ref)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _layer_scans(jaxpr):
+    """The scan equations of a closed jaxpr, outermost first."""
+    return [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"]
+
+
+def test_forward_and_loss_trace_without_the_kv_output():
+    """The K/V output is opt-in below ``forward``: ``forward`` still returns
+    (logits, aux), and the layer scan that ``loss_fn`` traces carries the
+    residual stream and emits nothing per layer, where the prefill's
+    ``forward_kv`` scan emits K and V."""
+    from repro.models.model import forward_kv
+
+    for cfg in (_cfg("qwen2-0.5b"), _cfg("mixtral-8x7b")):
+        params = init_params(cfg, KEY)
+        toks = jax.random.randint(KEY, (2, 8), 0, cfg.vocab_size)
+        batch = {"tokens": toks, "labels": toks}
+        out = jax.eval_shape(lambda p: forward(p, batch, cfg), params)
+        assert isinstance(out, tuple) and len(out) == 2
+        assert out[0].shape == (2, 8, cfg.vocab_size) and out[0].dtype == jnp.float32
+        assert out[1].shape == () and out[1].dtype == jnp.float32
+
+        (scan,) = _layer_scans(jax.make_jaxpr(lambda p: loss_fn(p, batch, cfg))(params))
+        assert len(scan.outvars) == scan.params["num_carry"]
+        (scan_kv,) = _layer_scans(jax.make_jaxpr(
+            lambda p: forward_kv(p, batch, cfg))(params))
+        assert len(scan_kv.outvars) == scan_kv.params["num_carry"] + 2
